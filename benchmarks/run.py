@@ -118,6 +118,8 @@ def main(argv=None) -> None:
             else:
                 ap.error(f"--only {args.only!r} matches none of {SUITES} "
                          f"(or 'fleet' for BENCH_fleet.json)")
+    from repro.core.runtime import enable_compile_cache
+    enable_compile_cache()
 
     failures = 0
     for name in suites:

@@ -23,8 +23,9 @@ from .machine import (HALT_EXIT, HALT_FUEL, HALT_KILL, HALT_SEGV, HALT_TRAP,
                       DecodedImage, MachineState, decode_image, make_state,
                       mem_read, mem_read_block, mem_write, run_image)
 from .rewriter import RewriteReport, rewrite_all_to_signal, rewrite_image
-from .runtime import (FleetImageTable, Mechanism, PreparedProcess,
-                      fleet_trace, hook_invocations, initial_state,
+from .runtime import (FleetImageTable, ImageTableFull, Mechanism,
+                      PreparedProcess, enable_compile_cache, fleet_trace,
+                      hook_invocations, initial_state,
                       pack_fleet, precompile_compact, prepare,
                       run_fleet_prepared, run_prepared, update_fleet_policy)
 from .scanner import SvcSite, census, scan_image
@@ -32,11 +33,12 @@ from .scanner import SvcSite, census, scan_image
 __all__ = [
     "C3Event", "DecodedImage", "FleetImageTable", "HALT_EXIT", "HALT_FUEL",
     "HALT_KILL", "HALT_SEGV", "HALT_TRAP", "HookConfig", "Image",
-    "MachineState", "Mechanism", "PinnedSite", "PolicyRule",
-    "PreparedProcess", "RewriteReport", "SvcSite", "TraceState",
+    "ImageTableFull", "MachineState", "Mechanism", "PinnedSite",
+    "PolicyRule", "PreparedProcess", "RewriteReport", "SvcSite", "TraceState",
     "admit_lanes", "build_minilibc", "build_process", "census",
     "choose_bucket", "compact_ladder", "costmodel", "decode_image",
-    "diagnose_c3", "diagnose_c3_fleet", "fleet", "fleet_counters",
+    "diagnose_c3", "diagnose_c3_fleet", "enable_compile_cache", "fleet",
+    "fleet_counters",
     "fleet_step", "fleet_step_traced", "fleet_summary", "fleet_trace",
     "hook_invocations", "initial_state", "isa", "layout",
     "make_halted_states", "make_state", "mem_read", "mem_read_block",

@@ -915,7 +915,9 @@ def _jitted_run_traced(chunk: int):
 # chunk loop is dispatched: "xla" scans _step_core with the full carry
 # re-materialised per step; "pallas" fuses the whole chunk into one
 # kernels.megastep dispatch with the carry resident in refs (interpret
-# mode on CPU, where it lowers back to the same XLA ops).
+# mode on CPU, where it lowers back to the same XLA ops).  The kernel does
+# not lower for a TPU: its carry operands are int64, and Mosaic refuses
+# their lane blocks, so a TPU backend refuses the engine up front.
 
 ENGINES = ("xla", "pallas")
 
@@ -924,6 +926,15 @@ def _check_engine(engine: str, *, shard: bool = False) -> str:
     if engine not in ENGINES:
         raise ValueError(
             f"unknown fleet engine {engine!r}: expected one of {ENGINES}")
+    if engine == "pallas" and jax.default_backend() == "tpu":
+        raise ValueError(
+            "engine='pallas' does not lower for a TPU: the megastep kernel's "
+            "carry operands are int64, and Mosaic refuses their lane blocks "
+            "('The Pallas TPU lowering currently requires that rank 1 block "
+            "shapes ... is a multiple of the tiling size (128 = 128 * "
+            "(32 // 32))'; a 128-lane block then fails the 64-bit tiling "
+            "128 * (32 // 64) = 0 with 'integer modulo by zero').  Use "
+            "engine='xla'; the 32-bit kernel rewrite is ROADMAP A2")
     if engine == "pallas" and shard:
         raise ValueError(
             "engine='pallas' does not compose with shard=True "
@@ -1300,7 +1311,7 @@ def run_fleet(imgs, states, img_ids=None, *, chunk: int = DEFAULT_CHUNK,
     ``chunk`` is the inner ``lax.scan`` length: loop-condition evaluation
     happens once per ``chunk`` steps.  Results are invariant to ``chunk``
     (only dispatch count changes).  ``shard=True`` lane-partitions the fleet
-    across available devices when the lane count divides the device count.
+    across available devices, whose count must divide the lane count.
 
     With ``trace`` (a :class:`TraceState`, donated like the states) the run
     records every executed svc into the per-lane rings and applies the

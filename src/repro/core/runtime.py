@@ -12,8 +12,11 @@ from __future__ import annotations
 import dataclasses
 import enum
 import hashlib
+import os
+import pathlib
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -132,9 +135,34 @@ def fleet_trace(pps: Sequence[PreparedProcess], *,
     return recorder.make_trace_state(len(pps), cap, policies=pols)
 
 
+# <repo root>/.jax_cache: a fixed path, because the cache directory is part
+# of what a later process must name to find the entries again
+_REPO_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads that directory
+    itself and nothing is overridden; otherwise the cache goes to the fixed
+    ``<repo root>/.jax_cache``.  Returns the directory in use.  Call it from
+    a program's ``main``, never at import: tests keep the cache off.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(_REPO_CACHE_DIR))
+    return str(_REPO_CACHE_DIR)
+
+
 def _image_digest(pp: PreparedProcess) -> bytes:
     return hashlib.sha1(
         np.ascontiguousarray(pp.image.words).tobytes()).digest()
+
+
+class ImageTableFull(RuntimeError):
+    """Every row of a :class:`FleetImageTable` is live: admission has to
+    wait until a running lane releases one."""
 
 
 class FleetImageTable:
@@ -183,14 +211,17 @@ class FleetImageTable:
             self.dedup_hits += 1
             return row
         if not self._free:
-            raise RuntimeError(
+            raise ImageTableFull(
                 f"FleetImageTable full ({self.capacity} rows all live); "
                 f"size the table to pool width + expected binary diversity")
-        row = self._free.pop(0)
+        row = self._free[0]
+        # the row leaves the free list only once the device write landed:
+        # a device error propagates and leaks nothing
+        self._images = F.set_image_row(self._images, row, pp.decoded)
+        self._free.pop(0)
         old = self._digest_of[row]
         if old is not None:              # evict the cached (dead) digest
             del self._row_of[old]
-        self._images = F.set_image_row(self._images, row, pp.decoded)
         self._row_of[d] = row
         self._digest_of[row] = d
         self._refs[row] = 1

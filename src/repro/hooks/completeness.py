@@ -38,7 +38,6 @@ def hlo_collective_census(hlo_text: str) -> Dict[str, int]:
 
 _JAXPR_TO_HLO = {
     "psum": "all-reduce", "psum_invariant": "all-reduce",
-    "psum2": "all-reduce",  # legacy shard_map tracing of psum
     "pmax": "all-reduce", "pmin": "all-reduce",
     "all_gather": "all-gather", "all_gather_invariant": "all-gather",
     "reduce_scatter": "reduce-scatter", "all_to_all": "all-to-all",

@@ -26,7 +26,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import sys
 import threading
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -34,10 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax._src.lax import parallel as _lp
 
-# The syscall table of this world.  Primitive names vary across jax
-# versions (e.g. ``psum_invariant_p`` only exists where shard_map traces
-# psum through it) — bind whatever this jax exposes and skip the rest, the
-# same way the scanner treats unknown collectives as out-of-scope sites.
+# The syscall table of this world: every collective primitive a traced
+# program can bind, shard_map's invariant spellings included.
 _PRIM_ATTRS = {
     "psum": "psum_p",
     "psum_invariant": "psum_invariant_p",
@@ -49,48 +46,8 @@ _PRIM_ATTRS = {
     "pmax": "pmax_p",
     "pmin": "pmin_p",
 }
-COLLECTIVE_PRIMS = {
-    name: getattr(_lp, attr)
-    for name, attr in _PRIM_ATTRS.items() if hasattr(_lp, attr)
-}
-
-# Legacy shard_map (jax without psum_invariant_p) rewrites a traced psum
-# into pbroadcast + psum2 — primitives living in the shard_map module, not
-# lax.parallel.  Register them so the hook's coverage (and the census) spans
-# that tracing scheme too.
-_LEGACY_REWRITE = False
-if "psum_invariant" not in COLLECTIVE_PRIMS:
-    try:
-        from jax.experimental import shard_map as _sm_mod
-        for _name, _attr in (("psum2", "psum2_p"), ("pbroadcast", "pbroadcast_p")):
-            if hasattr(_sm_mod, _attr):
-                COLLECTIVE_PRIMS[_name] = getattr(_sm_mod, _attr)
-        _LEGACY_REWRITE = "psum2" in COLLECTIVE_PRIMS
-    except Exception:  # pragma: no cover - no shard_map module at all
-        pass
-
-# The legacy replication-check rewrite *re-interprets* the already-traced
-# jaxpr (scan/cond/pjit bodies included), re-binding every collective a
-# second time.  Those binds are not new user sites — the handler already ran
-# (and its effects were recorded) during the initial trace — so they must
-# not re-enter the hook.  The re-interpretation always runs under one of
-# these shard_map-internal frames.
-_REWRITE_FRAMES = frozenset({
-    "_replication_rewrite_match", "_replication_rewrite_nomatch",
-    "_rewrite_subtrace",
-})
-
-
-def _in_legacy_rewrite() -> bool:
-    if not _LEGACY_REWRITE:
-        return False
-    f = sys._getframe()
-    while f is not None:
-        if (f.f_code.co_name in _REWRITE_FRAMES
-                and f.f_code.co_filename.endswith("shard_map.py")):
-            return True
-        f = f.f_back
-    return False
+COLLECTIVE_PRIMS = {name: getattr(_lp, attr)
+                    for name, attr in _PRIM_ATTRS.items()}
 
 # Handler signature: (prim_name, args, params, do_original) -> outputs
 # where do_original(*new_args, **param_overrides) re-executes the original
@@ -113,15 +70,12 @@ _ORIG_BINDS: Dict[str, Callable] = {}
 def _current_handler(name: str) -> Optional[Handler]:
     if _STATE.in_handler or not _STATE.stack:
         return None
-    if _in_legacy_rewrite():
-        return None  # re-interpretation of an already-hooked trace
-    # aliases: psum_invariant (modern) / psum2 (legacy) are how lax.psum
-    # traces inside shard_map; pbroadcast is replication bookkeeping (no
-    # wire traffic) and is only intercepted when named explicitly
+    # aliases: psum_invariant / all_gather_invariant are how lax.psum /
+    # lax.all_gather trace inside shard_map
     table = _STATE.stack[-1]
     if name in table:
         return table[name]
-    base = {"psum_invariant": "psum", "psum2": "psum",
+    base = {"psum_invariant": "psum",
             "all_gather_invariant": "all_gather"}.get(name)
     return table.get(base) if base else None
 
@@ -152,9 +106,8 @@ def _make_bind(prim, orig_bind):
             _STATE.in_handler = False
 
         # normalise arity: a handler may return a bare array for a
-        # one-output multiple-results primitive (psum_p is multi-result on
-        # some jax versions, psum_invariant is not — handlers should not
-        # have to care)
+        # one-output multiple-results primitive (psum_p is multi-result,
+        # psum_invariant is not — handlers should not have to care)
         if prim.multiple_results and not isinstance(out, (tuple, list)):
             out = (out,)
         outs = out if prim.multiple_results else (out,)

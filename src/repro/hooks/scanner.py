@@ -17,14 +17,8 @@ import numpy as np
 COLLECTIVE_NAMES = {
     "psum", "psum_invariant", "all_gather", "all_gather_invariant",
     "reduce_scatter", "all_to_all", "ppermute", "pmax", "pmin",
-    "unreduced_psum", "psum2",
+    "unreduced_psum",
 }
-
-# Legacy shard_map traces lax.psum as pbroadcast + psum2; psum2 is the
-# communicating site (canonical name: psum_invariant, so census numbers are
-# jax-version independent), pbroadcast is replication bookkeeping with no
-# wire traffic and is deliberately NOT a site.
-_CANONICAL = {"psum2": "psum_invariant"}
 
 
 @dataclasses.dataclass
@@ -66,9 +60,8 @@ def scan_jaxpr(closed_jaxpr, path: str = "", trip: int = 1) -> List[CollectiveSi
     sites: List[CollectiveSite] = []
     counter: Dict[str, int] = {}
     for eqn in closed_jaxpr.jaxpr.eqns:
-        raw = eqn.primitive.name
-        name = _CANONICAL.get(raw, raw)
-        if raw in COLLECTIVE_NAMES:
+        name = eqn.primitive.name
+        if name in COLLECTIVE_NAMES:
             idx = counter.get(name, 0)
             counter[name] = idx + 1
             sites.append(CollectiveSite(

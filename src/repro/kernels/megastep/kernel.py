@@ -15,11 +15,11 @@ table :mod:`repro.core.opspec`), so pallas==xla bit-exactness holds by
 construction and a new syscall family remains one spec-table row — there
 is no third copy of the semantics to keep in sync.
 
-On hosts without an accelerator Pallas backend (CPU — the tier-1 test
-environment) the kernel runs in interpret mode, which lowers to the same
-XLA ops as the reference engine; the fused-residency win is realised on
-accelerator backends where the carry stays in fast on-chip memory for
-the whole chunk.
+On the CPU backend (the tier-1 test environment) the kernel runs in
+interpret mode, which lowers to the same XLA ops as the reference engine.
+It does not lower for a TPU yet: the carry is int64, and Mosaic refuses
+64-bit lane blocks (ROADMAP A2), so the fleet refuses ``engine="pallas"``
+on a TPU backend.
 """
 from __future__ import annotations
 
@@ -42,13 +42,14 @@ _N_TBL = len(opspec.SpecTables._fields)
 
 
 def default_interpret() -> bool:
-    """Interpret unless an accelerator Pallas backend is available.
+    """Interpret on the CPU backend only.
 
     CPU has no Pallas lowering, so tier-1 (and any forced-host run via
-    ``JAX_PLATFORMS=cpu``) always takes the interpret path and never
-    needs an accelerator.
+    ``JAX_PLATFORMS=cpu``) takes the interpret path; every other backend
+    lowers the kernel for real (and a TPU refuses the engine before it
+    gets here — :func:`repro.core.fleet._check_engine`).
     """
-    return jax.default_backend() not in ("tpu", "gpu")
+    return jax.default_backend() == "cpu"
 
 
 def _full_spec(shape):

@@ -107,16 +107,13 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # older jax returns one dict per device
-        cost = cost[0] if cost else {}
     stats = analyze(compiled.as_text())
     terms = roofline_terms(stats)
     model_fl = model_flops_per_step(cfg, shape) / chips  # per device
 
     live_bytes = int(mem.argument_size_in_bytes + mem.temp_size_in_bytes
                      + mem.output_size_in_bytes - mem.alias_size_in_bytes)
-    # older jaxlib has no peak_memory_in_bytes on CompiledMemoryStats
-    peak_bytes = int(getattr(mem, "peak_memory_in_bytes", 0) or live_bytes)
+    peak_bytes = int(mem.peak_memory_in_bytes or live_bytes)
     cell.update(
         status="OK",
         compile_s=round(obs_now() - t0, 1),

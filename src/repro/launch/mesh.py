@@ -16,38 +16,19 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and the
-    ``AxisType`` enum) only exist in newer releases; older ones default every
-    axis to Auto, which is exactly what we pass anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis in Auto mode."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def shard_map_fn():
-    """``jax.shard_map`` where present, else the experimental spelling."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    from jax.experimental.shard_map import shard_map
-    return shard_map
+    """The ``shard_map`` transform (``jax.shard_map``)."""
+    return jax.shard_map
 
 
 def mesh_context(mesh):
-    """Activate ``mesh`` for sharding-constraint resolution, across versions.
-
-    Newer jax: ``jax.sharding.set_mesh`` (abstract-mesh context).  Older jax:
-    the ``Mesh`` object itself is the context manager (thread resources).
-    """
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    """Activate ``mesh`` for sharding-constraint resolution."""
+    return jax.sharding.set_mesh(mesh)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

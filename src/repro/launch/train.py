@@ -13,6 +13,7 @@ import jax
 
 from repro.configs import ARCHS, get_config, get_smoke
 from repro.configs.base import RunConfig, ShapeConfig
+from repro.core.runtime import enable_compile_cache
 from repro.train.loop import run_training
 
 
@@ -31,6 +32,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.full_config else get_smoke(args.arch)
     shape = ShapeConfig("cli", args.seq_len, args.global_batch, "train")

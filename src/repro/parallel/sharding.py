@@ -47,23 +47,9 @@ def tp_axis():
 
 
 def abstract_mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is None or not m.axis_names:
-            return None
-        return m
-    except Exception:
-        pass
-    # older jax: no abstract-mesh context; fall back to the thread-resources
-    # mesh installed by ``with mesh:`` / launch.mesh.mesh_context
-    try:
-        from jax._src import mesh as _mesh_lib
-        pm = _mesh_lib.thread_resources.env.physical_mesh
-        if pm is None or pm.empty or not pm.axis_names:
-            return None
-        return pm.abstract_mesh
-    except Exception:
-        return None
+    """The mesh installed by ``launch.mesh.mesh_context``, else None."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
 
 
 def mesh_axis_size(name: str) -> int:
@@ -90,11 +76,8 @@ def _filter_spec(spec: P) -> Optional[P]:
     m = abstract_mesh()
     if m is None:
         return None
-    try:
-        auto = {n for n, t in zip(m.axis_names, m.axis_types)
-                if "Auto" in str(t)}
-    except Exception:
-        auto = set(m.axis_names)
+    auto = {n for n, t in zip(m.axis_names, m.axis_types)
+            if t == jax.sharding.AxisType.Auto}
     if not auto:
         return None
 
@@ -236,15 +219,23 @@ def lane_sharding(mesh, extra_dims: int = 0):
         mesh, P(LANE_AXIS, *([None] * extra_dims)))
 
 
+def _check_divides(n_lanes: int, ndev: int) -> None:
+    if ndev > 1 and n_lanes % ndev:
+        raise ValueError(
+            f"a {n_lanes}-lane fleet cannot be split evenly over {ndev} "
+            f"devices: use a lane count that is a multiple of {ndev}")
+
+
 def fleet_divisor(n_lanes: int, mesh=None) -> int:
     """The lane-count divisor a partitioned fleet must respect: the device
-    count when it divides ``n_lanes`` (so :func:`shard_fleet` actually
-    partitions), else 1 (the replicated fallback).  Feed it to
+    count (1 on a single device).  Feed it to
     ``fleet.compact_ladder(divisor=...)`` for per-shard bucket ladders —
-    every rung then keeps an equal lane slice per device."""
+    every rung then keeps an equal lane slice per device.  Raises
+    ``ValueError`` when several devices do not divide ``n_lanes``."""
     mesh = mesh or fleet_mesh()
     ndev = int(np.prod(mesh.devices.shape))
-    return ndev if ndev > 1 and n_lanes % ndev == 0 else 1
+    _check_divides(n_lanes, ndev)
+    return ndev
 
 
 def shard_fleet(imgs, img_ids, states, mesh=None, trace=None):
@@ -252,15 +243,16 @@ def shard_fleet(imgs, img_ids, states, mesh=None, trace=None):
     deduplicated decode tables replicated.  ``trace`` (a fleet
     ``TraceState``) is lane-leading like the states and splits the same way.
 
-    No-op (returns inputs unchanged) on a single device or when the device
-    count does not divide the lane count — the fleet then runs fully
-    replicated, which is always correct.  Returns a 4-tuple iff ``trace``
-    was passed.
+    No-op (returns inputs unchanged) on a single device.  Raises
+    ``ValueError`` when several devices do not divide the lane count,
+    rather than running the whole fleet on every device.  Returns a
+    4-tuple iff ``trace`` was passed.
     """
     mesh = mesh or fleet_mesh()
     ndev = int(np.prod(mesh.devices.shape))
     n_lanes = int(states.pc.shape[0])
-    if ndev <= 1 or n_lanes % ndev != 0:
+    _check_divides(n_lanes, ndev)
+    if ndev == 1:
         return ((imgs, img_ids, states) if trace is None
                 else (imgs, img_ids, states, trace))
 

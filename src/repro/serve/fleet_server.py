@@ -77,8 +77,8 @@ from repro.core import machine as M
 from repro.core.completeness import C3Event, diagnose_c3_fleet
 from repro.core.hookcfg import HookConfig, PolicyRule
 from repro.core.isa import Asm
-from repro.core.runtime import (FleetImageTable, Mechanism, PreparedProcess,
-                                initial_state, prepare)
+from repro.core.runtime import (FleetImageTable, ImageTableFull, Mechanism,
+                                PreparedProcess, initial_state, prepare)
 from repro.obs import ObsHub
 from repro.obs import now as obs_now
 from repro.obs import phase as obs_phase
@@ -161,8 +161,8 @@ class FleetServer:
     generation (scheduling granularity — results are invariant to it);
     ``table_capacity`` bounds how many distinct binaries can be resident at
     once (pool width + expected diversity).  ``shard=True`` lane-partitions
-    the pool across local devices via :mod:`repro.parallel.sharding` when
-    the device count divides ``pool``.
+    the pool across local devices via :mod:`repro.parallel.sharding`; the
+    device count must divide ``pool``.
     """
 
     def __init__(self, pool: int = 8, *, cfg: Optional[HookConfig] = None,
@@ -844,7 +844,7 @@ class FleetServer:
                 if cand.checkpoint is None:
                     try:
                         cand.row = self.table.admit(cand.pp)
-                    except RuntimeError:
+                    except ImageTableFull:
                         # table transiently full: rows free as lanes
                         # finish.  Without a scheduler the FIFO head
                         # blocks (the pre-scheduler behavior); with one,
@@ -985,7 +985,7 @@ class FleetServer:
                 else:
                     try:
                         new_row = self.table.admit(new_pp)
-                    except RuntimeError:
+                    except ImageTableFull:
                         new_row = None
                     if new_row is not None:
                         self.table.release(req.row)

@@ -128,12 +128,8 @@ def make_ddp_train_step(cfg: ModelConfig, run: RunConfig, mesh,
     batch_specs = P(data_axis)
     from repro.launch.mesh import shard_map_fn
     sm = shard_map_fn()
-    kwargs = dict(mesh=mesh, in_specs=(state_specs, batch_specs),
-                  out_specs=(state_specs, P()))
-    try:
-        return sm(local_step, check_vma=False, **kwargs)
-    except TypeError:  # older jax spells the replication check check_rep
-        return sm(local_step, check_rep=False, **kwargs)
+    return sm(local_step, mesh=mesh, in_specs=(state_specs, batch_specs),
+              out_specs=(state_specs, P()), check_vma=False)
 
 
 def make_serve_steps(cfg: ModelConfig, run: RunConfig):

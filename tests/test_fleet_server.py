@@ -158,6 +158,40 @@ def test_admission_waits_out_a_full_table():
     assert srv.table.live_rows() == 0
 
 
+def test_device_error_in_image_write_propagates(monkeypatch):
+    """A device error while writing an image row (e.g. RESOURCE_EXHAUSTED,
+    a RuntimeError like the table-full one) leaves ``run()`` instead of
+    being read as a full table, and the row it was about to take stays
+    free: once the device recovers the same request is served."""
+    from repro.core import fleet as F
+    srv = FleetServer(pool=2, gen_steps=64, fuel=FUEL, table_capacity=2)
+    rid = srv.submit(_pp("getpid", Mechanism.ASC), regs={19: 4})
+    real = F.set_image_row
+
+    def failing(*args, **kwargs):
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of device memory")
+
+    monkeypatch.setattr(F, "set_image_row", failing)
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        srv.run()
+    assert srv.table.live_rows() == 0 and srv.table.admissions == 0
+    monkeypatch.setattr(F, "set_image_row", real)
+    res = srv.run()
+    assert [r.rid for r in res] == [rid]
+    _assert_state_equal(_ref("getpid", Mechanism.ASC, 4), res[0].state,
+                        "request served after the device error")
+    assert srv.table.live_rows() == 0
+
+
+def test_image_table_full_is_its_own_error():
+    from repro.core import FleetImageTable, ImageTableFull
+    tbl = FleetImageTable(1)
+    tbl.admit(_pp("getpid", Mechanism.ASC))
+    with pytest.raises(ImageTableFull):
+        tbl.admit(_pp("read", Mechanism.SIGNAL))
+    assert tbl.live_rows() == 1
+
+
 def test_image_table_dedups_and_recycles_rows():
     srv = FleetServer(pool=2, gen_steps=64, fuel=FUEL, table_capacity=3)
     pp = _pp("getpid", Mechanism.ASC)

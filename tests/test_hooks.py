@@ -1,27 +1,14 @@
 """Collective interception layer (the paper's technique, adapted to SPMD)."""
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.hooks import (COLLECTIVE_PRIMS, CastCompressHandler, RSAGHandler,
-                         TraceHandler, census_fn, completeness_report,
+from repro.hooks import (CastCompressHandler, RSAGHandler, TraceHandler,
+                         census_fn, completeness_report,
                          hlo_collective_census, hook_collectives, hooking,
                          scan_jaxpr, virtualize)
-
-# On older jax, shard_map traces lax.psum through psum2/pbroadcast rather
-# than psum_invariant.  The interceptor registers and aliases the legacy
-# primitives (and the census canonicalises psum2 -> psum_invariant), so both
-# tracing schemes are covered; the gate only remains for a jax exposing
-# neither scheme.
-_LEGACY_SHARD_MAP = not ({"psum_invariant", "psum2"} & COLLECTIVE_PRIMS.keys())
-legacy_shard_map_xfail = pytest.mark.xfail(
-    _LEGACY_SHARD_MAP, strict=False,
-    reason="this jax traces shard_map psum through primitives the "
-           "interceptor does not expose")
 
 N_DEV = jax.device_count()
 pytestmark = pytest.mark.skipif(N_DEV < 1, reason="needs a device")
@@ -59,7 +46,6 @@ X = jnp.arange(16.0 * 256, dtype=jnp.float32).reshape(16, 256)
 
 # -- static census (Table 1/2 analogue) --------------------------------------
 
-@legacy_shard_map_xfail
 def test_census_finds_nested_sites():
     c = census_fn(make_sm(), X)
     assert c["total_sites"] == 2
@@ -70,7 +56,6 @@ def test_census_finds_nested_sites():
     assert any("scan/" in p for p in paths), paths
 
 
-@legacy_shard_map_xfail
 def test_census_loop_trip_counts():
     c = census_fn(make_sm(), X)
     trips = {s.path: s.loop_trip for s in c["sites"]}
@@ -79,7 +64,6 @@ def test_census_loop_trip_counts():
 
 # -- interception (the trampoline) --------------------------------------------
 
-@legacy_shard_map_xfail
 def test_trace_handler_is_transparent():
     sm = make_sm()
     th = TraceHandler()
@@ -140,7 +124,6 @@ def test_hook_works_under_jit_and_grad():
     assert th.count >= 2
 
 
-@legacy_shard_map_xfail
 def test_no_recursive_interception():
     """Handlers may themselves use collectives (dlmopen-namespace analogue)."""
     calls = []
@@ -165,7 +148,6 @@ def test_transparency_check_rejects_bad_handler():
         hook_collectives(make_sm(), {"psum": bad})(X)
 
 
-@legacy_shard_map_xfail
 def test_hooks_compose_with_stack():
     th_outer, th_inner = TraceHandler(), TraceHandler()
     with hooking({"psum": th_outer}):
@@ -174,17 +156,13 @@ def test_hooks_compose_with_stack():
     assert th_inner.count == 2 and th_outer.count == 0
 
 
-@legacy_shard_map_xfail
 def test_virtualize_skips_collective():
     # a fabricated result is device-varying as far as shard_map's replication
     # checker knows, so the harness disables check_vma (the virtualised value
     # is the benchmark's concern, not the type system's)
     mesh = make_mesh()
-    kwargs = dict(mesh=mesh, in_specs=P(None, None), out_specs=P(None, None))
-    try:
-        sm = _shard_map(dp_step, check_vma=False, **kwargs)
-    except TypeError:  # older jax spells it check_rep
-        sm = _shard_map(dp_step, check_rep=False, **kwargs)
+    sm = _shard_map(dp_step, mesh=mesh, in_specs=P(None, None),
+                    out_specs=P(None, None), check_vma=False)
     vh = virtualize(lambda args: args[0] * 0.0)
     y = hook_collectives(sm, {"psum": vh})(X)
     assert bool(jnp.all(y == 0))
@@ -192,7 +170,6 @@ def test_virtualize_skips_collective():
 
 # -- shipped feature handlers --------------------------------------------------
 
-@legacy_shard_map_xfail
 def test_cast_compress_halves_wire_bytes():
     ch = CastCompressHandler(min_bytes=1024)
     y0 = make_sm()(X)
@@ -202,7 +179,6 @@ def test_cast_compress_halves_wire_bytes():
     assert float(err) < 0.02  # bf16 wire error
 
 
-@legacy_shard_map_xfail
 def test_rsag_schedule_rewrite_is_exact():
     rh = RSAGHandler(axis_size=N_DEV)
     y0 = make_sm()(X)
@@ -224,7 +200,6 @@ def test_hlo_census_counts_collectives():
     assert counts.get("all-reduce", 0) >= 1
 
 
-@legacy_shard_map_xfail
 def test_completeness_report_structure():
     c = census_fn(make_sm(), X)
     txt = jax.jit(make_sm()).lower(X).compile().as_text()

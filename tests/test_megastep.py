@@ -199,6 +199,21 @@ def test_engine_validation():
         run_fleet_prepared(pps[:2], fuel=1000, engine="pallas", shard=True)
 
 
+def test_pallas_refused_on_a_tpu_backend(monkeypatch):
+    """The kernel's int64 blocks do not lower for a TPU: the engine is
+    refused up front (a server fails at construction, not inside a
+    generation), and no kernel is interpreted off the CPU backend."""
+    from repro.serve.fleet_server import FleetServer
+    assert default_interpret()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="Mosaic"):
+        fleet._check_engine("pallas")
+    with pytest.raises(ValueError, match="ROADMAP A2"):
+        FleetServer(pool=2, engine="pallas")
+    assert fleet._check_engine("xla") == "xla"
+    assert not default_interpret()
+
+
 def test_hookcfg_engine_roundtrip(tmp_path):
     cfg = HookConfig(fleet_engine="pallas")
     path = tmp_path / "hook.json"
