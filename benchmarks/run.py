@@ -36,7 +36,6 @@
                                file-churn census (<15% bar, zero -ENOSYS
                                fall-throughs, xla==pallas bit-identity);
                                writes BENCH_emul.json itself
-  * roofline                 — dry-run roofline table (§Roofline)
 
 Besides the CSV stream, writes ``benchmarks/results/BENCH_fleet.json`` with
 machine-readable per-mechanism per-call cycles and the scalar-vs-fleet
@@ -57,7 +56,7 @@ import traceback
 SUITES = ["hook_overhead", "svc_census", "app_bandwidth", "collective_census",
           "collective_hook_overhead", "serving_throughput", "trace_overhead",
           "compaction_speedup", "policy_scheduler", "durability_overhead",
-          "obs_overhead", "emul_overhead", "roofline"]
+          "obs_overhead", "emul_overhead"]
 
 # suites feeding the BENCH_fleet.json record (collect_fleet_bench)
 _FLEET_BENCH_INPUTS = {"hook_overhead", "collective_hook_overhead"}

@@ -203,17 +203,18 @@ def _fetch(img: FleetImages, ids: jnp.ndarray, pc0: jnp.ndarray):
     """Fetch + decode for every lane: two gathers (packed fields + imm),
     then bit-unpack.  Returns the per-lane field tuple ``(op, rd, rn, rm,
     sh, cond, sf, imm)`` that :func:`exec_lanes` consumes."""
-    ok_fetch = (pc0 >= 0) & (pc0 < L.CODE_LIMIT) & ((pc0 & 3) == 0)
-    idx = jnp.clip(pc0 >> 2, 0, L.CODE_WORDS - 1)
-    w = img.packed[ids, idx]
-    imm = img.imm[ids, idx]
-    op = jnp.where(ok_fetch, (w & 63).astype(I32), I32(int(Op.NULLPAGE)))
-    rd = ((w >> 6) & 31).astype(I32)
-    rn = ((w >> 11) & 31).astype(I32)
-    rm = ((w >> 16) & 31).astype(I32)
-    sh = ((w >> 22) & 63).astype(I32)
-    cond = ((w >> 28) & 15).astype(I32)
-    sf = ((w >> 32) & 1).astype(I32)
+    with jax.named_scope("fetch"):
+        ok_fetch = (pc0 >= 0) & (pc0 < L.CODE_LIMIT) & ((pc0 & 3) == 0)
+        idx = jnp.clip(pc0 >> 2, 0, L.CODE_WORDS - 1)
+        w = img.packed[ids, idx]
+        imm = img.imm[ids, idx]
+        op = jnp.where(ok_fetch, (w & 63).astype(I32), I32(int(Op.NULLPAGE)))
+        rd = ((w >> 6) & 31).astype(I32)
+        rn = ((w >> 11) & 31).astype(I32)
+        rm = ((w >> 16) & 31).astype(I32)
+        sh = ((w >> 22) & 63).astype(I32)
+        cond = ((w >> 28) & 15).astype(I32)
+        sf = ((w >> 32) & 1).astype(I32)
     return op, rd, rn, rm, sh, cond, sf, imm
 
 
@@ -240,6 +241,12 @@ def exec_lanes(fields, s: MachineState, tr: Optional[TraceState],
     :data:`opspec.TABLES` constants) — the Pallas kernel passes the
     columns it received as operands, since kernels cannot capture array
     constants.
+
+    Its parts run under ``jax.named_scope`` (``fetch`` for the op-class
+    decode, ``regs``, ``mem``, ``alu``, ``syscall``, ``emul``,
+    ``io_mover``, ``trace_ring``), so a device trace can charge each
+    operation to one; scopes change op metadata only, never instruction
+    names.
     """
     traced = tr is not None
     if tbl is None:
@@ -257,243 +264,252 @@ def exec_lanes(fields, s: MachineState, tr: Optional[TraceState],
     # Tiny-constant gathers (like COST_TABLE[op]) followed by equality
     # masks; every mask below is one class compare, not a hand-written
     # per-op union, so a new opcode is a table row away.
-    aluc = tbl.ALU[op]
-    flagc = tbl.FLAGS[op]
-    memc = tbl.MEM[op]
-    pcc = tbl.PC[op]
+    with jax.named_scope("fetch"):
+        aluc = tbl.ALU[op]
+        flagc = tbl.FLAGS[op]
+        memc = tbl.MEM[op]
+        pcc = tbl.PC[op]
 
-    def c(tbl, v):
-        return (tbl == v) & act
+        def c(tbl, v):
+            return (tbl == v) & act
 
-    m_svc = c(pcc, opspec.P_SVC)
-    m_null = tbl.SEGV[op] & act
-    m_hlt = tbl.EXIT[op] & act
-    dlv = c(pcc, opspec.P_TRAP)
-    ld_single = c(memc, opspec.M_LOAD)
-    st_single = c(memc, opspec.M_STORE)
-    ld_pair = c(memc, opspec.M_LOAD_P)
-    st_pair = c(memc, opspec.M_STORE_P)
-    byte_op = c(memc, opspec.M_LOAD_BYTE) | c(memc, opspec.M_STORE_BYTE)
+        m_svc = c(pcc, opspec.P_SVC)
+        m_null = tbl.SEGV[op] & act
+        m_hlt = tbl.EXIT[op] & act
+        dlv = c(pcc, opspec.P_TRAP)
+        ld_single = c(memc, opspec.M_LOAD)
+        st_single = c(memc, opspec.M_STORE)
+        ld_pair = c(memc, opspec.M_LOAD_P)
+        st_pair = c(memc, opspec.M_STORE_P)
+        byte_op = c(memc, opspec.M_LOAD_BYTE) | c(memc, opspec.M_STORE_BYTE)
 
     # -- register reads (reg 31 is XZR for _rr, SP for _rsp) -----------------
-    zero = jnp.zeros((B,), I64)
-    ra = jnp.clip(imm, 0, 31).astype(I32)  # madd packs ra into imm
-    ridx = jnp.stack([jnp.minimum(rn, 30), jnp.minimum(rm, 30),
-                      jnp.minimum(rd, 30), jnp.minimum(ra, 30)],
-                     axis=1).astype(I32)
-    rvals = jnp.take_along_axis(regs0, ridx, axis=1)  # one gather, [B, 4]
-    rn_raw, rm_raw, rd_raw, ra_raw = (rvals[:, 0], rvals[:, 1],
-                                      rvals[:, 2], rvals[:, 3])
-    rn_rr = jnp.where(rn == 31, zero, rn_raw)
-    rn_rsp = jnp.where(rn == 31, sp0, rn_raw)
-    rm_rr = jnp.where(rm == 31, zero, rm_raw)
-    rd_rr = jnp.where(rd == 31, zero, rd_raw)
-    ra_rr = jnp.where(ra == 31, zero, ra_raw)
-    x0, x1, x2, x8 = regs0[:, 0], regs0[:, 1], regs0[:, 2], regs0[:, 8]
+    with jax.named_scope("regs"):
+        zero = jnp.zeros((B,), I64)
+        ra = jnp.clip(imm, 0, 31).astype(I32)  # madd packs ra into imm
+        ridx = jnp.stack([jnp.minimum(rn, 30), jnp.minimum(rm, 30),
+                          jnp.minimum(rd, 30), jnp.minimum(ra, 30)],
+                         axis=1).astype(I32)
+        rvals = jnp.take_along_axis(regs0, ridx, axis=1)  # one gather, [B, 4]
+        rn_raw, rm_raw, rd_raw, ra_raw = (rvals[:, 0], rvals[:, 1],
+                                          rvals[:, 2], rvals[:, 3])
+        rn_rr = jnp.where(rn == 31, zero, rn_raw)
+        rn_rsp = jnp.where(rn == 31, sp0, rn_raw)
+        rm_rr = jnp.where(rm == 31, zero, rm_raw)
+        rd_rr = jnp.where(rd == 31, zero, rd_raw)
+        ra_rr = jnp.where(ra == 31, zero, ra_raw)
+        x0, x1, x2, x8 = regs0[:, 0], regs0[:, 1], regs0[:, 2], regs0[:, 8]
 
     # -- memory addressing: <=2 word gathers, <=2 word scatters per step -----
-    post_index = tbl.ADDR_POST[op] & act
-    addr_a = jnp.where(post_index, rn_rsp, rn_rsp + imm)
-    eff1 = jnp.where(byte_op, addr_a & ~jnp.int64(7), addr_a)
-    ok1 = jnp.where(byte_op,
-                    (addr_a >= L.DATA_BASE) & (addr_a < L.MEM_LIMIT),
-                    _mem_ok_v(eff1))
-    addr2 = addr_a + 8
-    ok2 = _mem_ok_v(addr2)
-    g1, g2 = _widx_v(eff1), _widx_v(addr2)
-    # Flat 1-D addressing: [B, MEM_WORDS] -> [B*MEM_WORDS] is a bitcast, and
-    # rank-1 gathers/scatters take XLA's fast in-place path on CPU.
-    mem_flat = mem0.reshape(-1)
-    lane_base = (lanes * L.MEM_WORDS).astype(I64)
-    # The word reads live behind a (vacuously true while any lane runs)
-    # batch-uniform cond.  Expressed as bare gathers, XLA's CPU pipeline
-    # wraps them in parallel-task `call`s whose buffer use its copy
-    # insertion cannot see through, and the whole [B, MEM_WORDS] carry gets
-    # defensively copied every step (~10x slowdown at fleet width 40);
-    # conditional branch reads keep the carry aliasable.
-    v1, v2 = lax.cond(
-        jnp.any(act),
-        lambda: (mem_flat[lane_base + g1], mem_flat[lane_base + g2]),
-        lambda: (jnp.zeros((B,), I64), jnp.zeros((B,), I64)))
+    with jax.named_scope("mem"):
+        post_index = tbl.ADDR_POST[op] & act
+        addr_a = jnp.where(post_index, rn_rsp, rn_rsp + imm)
+        eff1 = jnp.where(byte_op, addr_a & ~jnp.int64(7), addr_a)
+        ok1 = jnp.where(byte_op,
+                        (addr_a >= L.DATA_BASE) & (addr_a < L.MEM_LIMIT),
+                        _mem_ok_v(eff1))
+        addr2 = addr_a + 8
+        ok2 = _mem_ok_v(addr2)
+        g1, g2 = _widx_v(eff1), _widx_v(addr2)
+        # Flat 1-D addressing: [B, MEM_WORDS] -> [B*MEM_WORDS] is a bitcast,
+        # and rank-1 gathers/scatters take XLA's fast in-place path on CPU.
+        mem_flat = mem0.reshape(-1)
+        lane_base = (lanes * L.MEM_WORDS).astype(I64)
+        # The word reads live behind a (vacuously true while any lane runs)
+        # batch-uniform cond.  Expressed as bare gathers, XLA's CPU pipeline
+        # wraps them in parallel-task `call`s whose buffer use its copy
+        # insertion cannot see through, and the whole [B, MEM_WORDS] carry gets
+        # defensively copied every step (~10x slowdown at fleet width 40);
+        # conditional branch reads keep the carry aliasable.
+        v1, v2 = lax.cond(
+            jnp.any(act),
+            lambda: (mem_flat[lane_base + g1], mem_flat[lane_base + g2]),
+            lambda: (jnp.zeros((B,), I64), jnp.zeros((B,), I64)))
 
-    byte_shift = (addr_a & 7) * 8
-    byte_val = (v1 >> byte_shift) & 0xFF
-    strb_word = ((v1 & ~(jnp.int64(0xFF) << byte_shift))
-                 | ((rd_rr & 0xFF) << byte_shift))
+        byte_shift = (addr_a & 7) * 8
+        byte_val = (v1 >> byte_shift) & 0xFF
+        strb_word = ((v1 & ~(jnp.int64(0xFF) << byte_shift))
+                     | ((rd_rr & 0xFF) << byte_shift))
 
-    ld1 = jnp.where(ok1, v1, zero)   # ldri/ldrpost/ldp/ldppost first word
-    ld2 = jnp.where(ok2, v2, zero)   # ldp/ldppost second word
+        ld1 = jnp.where(ok1, v1, zero)   # ldri/ldrpost/ldp/ldppost first word
+        ld2 = jnp.where(ok2, v2, zero)   # ldp/ldppost second word
 
     # -- ALU / mov / load value for the primary register write --------------
     # One select row per ALU class column (opspec.ALU); class masks are
     # disjoint by construction, so row order cannot change results.
-    piece = imm << sh64
-    movk_v = (rd_rr & ~(jnp.int64(0xFFFF) << sh64)) | piece
-    mov_v = jnp.select([c(aluc, opspec.A_MOVZ), c(aluc, opspec.A_MOVN),
-                        c(aluc, opspec.A_MOVK)],
-                       [piece, ~piece, movk_v], zero)
-    mov_v = jnp.where(sf == 1, mov_v, mov_v & jnp.int64(0xFFFFFFFF))
+    with jax.named_scope("alu"):
+        piece = imm << sh64
+        movk_v = (rd_rr & ~(jnp.int64(0xFFFF) << sh64)) | piece
+        mov_v = jnp.select([c(aluc, opspec.A_MOVZ), c(aluc, opspec.A_MOVN),
+                            c(aluc, opspec.A_MOVK)],
+                           [piece, ~piece, movk_v], zero)
+        mov_v = jnp.where(sf == 1, mov_v, mov_v & jnp.int64(0xFFFFFFFF))
 
-    slotA_val = jnp.select(
-        [c(aluc, opspec.A_MOVZ) | c(aluc, opspec.A_MOVN)
-         | c(aluc, opspec.A_MOVK),
-         c(aluc, opspec.A_ADRP),
-         c(aluc, opspec.A_ADR),
-         c(aluc, opspec.A_ADD_I),
-         c(aluc, opspec.A_SUB_I),
-         c(aluc, opspec.A_ADD_R),
-         c(aluc, opspec.A_SUB_R),
-         c(aluc, opspec.A_ORR),
-         c(aluc, opspec.A_AND),
-         c(aluc, opspec.A_EOR),
-         c(aluc, opspec.A_MADD),
-         c(aluc, opspec.A_LSL),
-         c(aluc, opspec.A_LOAD),
-         c(aluc, opspec.A_LOAD_B),
-         c(aluc, opspec.A_LINK)],
-        [mov_v,
-         (pc0 & ~jnp.int64(0xFFF)) + imm,
-         pc0 + imm,
-         rn_rsp + imm,
-         rn_rsp - imm,
-         rn_rr + rm_rr,
-         rn_rr - rm_rr,
-         rn_rr | rm_rr,
-         rn_rr & rm_rr,
-         rn_rr ^ rm_rr,
-         rn_rr * rm_rr + ra_rr,
-         rn_rr << sh64,
-         ld1,
-         byte_val,
-         pc0 + 4],
-        zero)
-    slotA_en = (aluc != opspec.A_NONE) & act
-    slotA_idx = jnp.where(tbl.WB_LR[op], I32(30), rd)
-    slotA_sp = tbl.WB_SP[op] & act  # _wsp ops: rd == 31 targets SP
+        slotA_val = jnp.select(
+            [c(aluc, opspec.A_MOVZ) | c(aluc, opspec.A_MOVN)
+             | c(aluc, opspec.A_MOVK),
+             c(aluc, opspec.A_ADRP),
+             c(aluc, opspec.A_ADR),
+             c(aluc, opspec.A_ADD_I),
+             c(aluc, opspec.A_SUB_I),
+             c(aluc, opspec.A_ADD_R),
+             c(aluc, opspec.A_SUB_R),
+             c(aluc, opspec.A_ORR),
+             c(aluc, opspec.A_AND),
+             c(aluc, opspec.A_EOR),
+             c(aluc, opspec.A_MADD),
+             c(aluc, opspec.A_LSL),
+             c(aluc, opspec.A_LOAD),
+             c(aluc, opspec.A_LOAD_B),
+             c(aluc, opspec.A_LINK)],
+            [mov_v,
+             (pc0 & ~jnp.int64(0xFFF)) + imm,
+             pc0 + imm,
+             rn_rsp + imm,
+             rn_rsp - imm,
+             rn_rr + rm_rr,
+             rn_rr - rm_rr,
+             rn_rr | rm_rr,
+             rn_rr & rm_rr,
+             rn_rr ^ rm_rr,
+             rn_rr * rm_rr + ra_rr,
+             rn_rr << sh64,
+             ld1,
+             byte_val,
+             pc0 + 4],
+            zero)
+        slotA_en = (aluc != opspec.A_NONE) & act
+        slotA_idx = jnp.where(tbl.WB_LR[op], I32(30), rd)
+        slotA_sp = tbl.WB_SP[op] & act  # _wsp ops: rd == 31 targets SP
 
-    # -- flags ---------------------------------------------------------------
-    f_imm = flagc == opspec.F_SUBS_I
-    subs = (flagc != opspec.F_NONE) & act
-    fa = jnp.where(f_imm, rn_rsp, rn_rr)
-    fb = jnp.where(f_imm, imm, rm_rr)
-    res = fa - fb
-    flag_n = (res < 0).astype(I64) * 8
-    flag_z = (res == 0).astype(I64) * 4
-    flag_c = (fa.astype(jnp.uint64) >= fb.astype(jnp.uint64)).astype(I64) * 2
-    flag_v = (((fa ^ fb) & (fa ^ res)) < 0).astype(I64)
-    nzcv = jnp.where(subs, flag_n + flag_z + flag_c + flag_v, nzcv0)
+        # -- flags -----------------------------------------------------------
+        f_imm = flagc == opspec.F_SUBS_I
+        subs = (flagc != opspec.F_NONE) & act
+        fa = jnp.where(f_imm, rn_rsp, rn_rr)
+        fb = jnp.where(f_imm, imm, rm_rr)
+        res = fa - fb
+        flag_n = (res < 0).astype(I64) * 8
+        flag_z = (res == 0).astype(I64) * 4
+        flag_c = (fa.astype(jnp.uint64)
+                  >= fb.astype(jnp.uint64)).astype(I64) * 2
+        flag_v = (((fa ^ fb) & (fa ^ res)) < 0).astype(I64)
+        nzcv = jnp.where(subs, flag_n + flag_z + flag_c + flag_v, nzcv0)
 
     # -- syscalls (scalar effects; the I/O word loop is under a cond below) --
-    nr = x8
-    in_pt = s.ptrace != 0
-    en = s.k_enabled != 0  # per-lane guest-kernel gate (0 = legacy stubs)
-    if traced:
-        # Seccomp-style gate: resolve nr to a per-lane policy action, then
-        # only ALLOW lanes reach the sys_* branches.  The lookup is a chain
-        # of [B] selects over the 8 table columns rather than a gather —
-        # take_along_axis here gets wrapped in CPU parallel-task calls
-        # (the same pipeline issue as the word reads above) and costs ~10%
-        # census throughput; the select chain fuses into the step for ~3%.
-        any_svc = jnp.any(m_svc)
-        action = tr.pol_action[:, SLOT_UNKNOWN]
-        pol_arg = tr.pol_arg[:, SLOT_UNKNOWN]
-        pol_slot = jnp.full((B,), SLOT_UNKNOWN, I64)
-        emulable = jnp.zeros((B,), bool)
-        for i, spec in enumerate(opspec.SYSCALLS):
-            hit = nr == spec.nr
-            action = jnp.where(hit, tr.pol_action[:, i], action)
-            pol_arg = jnp.where(hit, tr.pol_arg[:, i], pol_arg)
-            pol_slot = jnp.where(hit, jnp.int64(i), pol_slot)
-            if spec.emul:
-                emulable = emulable | hit
-        pol_deny = m_svc & (action == POL_DENY)
-        pol_emul = m_svc & (action == POL_EMULATE)
-        pol_kill = m_svc & (action == POL_KILL)
-        # An EMULATE verdict on a guest-kernel-backed nr routes into the
-        # emulation branch (real fd-table service); on anything else it
-        # returns the policy constant, as it always did.  Both record the
-        # POL_EMULATE verdict and feed emul_count.
-        emul_route = pol_emul & emulable & en
-        pol_emul_const = pol_emul & ~(emulable & en)
-        svc_exec = m_svc & ((action == POL_ALLOW) | emul_route)
-    else:
-        svc_exec = m_svc
+    with jax.named_scope("syscall"):
+        nr = x8
+        in_pt = s.ptrace != 0
+        en = s.k_enabled != 0  # per-lane guest-kernel gate (0 = legacy stubs)
+        if traced:
+            # Seccomp-style gate: resolve nr to a per-lane policy action, then
+            # only ALLOW lanes reach the sys_* branches.  The lookup is a chain
+            # of [B] selects over the 8 table columns rather than a gather —
+            # take_along_axis here gets wrapped in CPU parallel-task calls
+            # (the same pipeline issue as the word reads above) and costs ~10%
+            # census throughput; the select chain fuses into the step for ~3%.
+            any_svc = jnp.any(m_svc)
+            action = tr.pol_action[:, SLOT_UNKNOWN]
+            pol_arg = tr.pol_arg[:, SLOT_UNKNOWN]
+            pol_slot = jnp.full((B,), SLOT_UNKNOWN, I64)
+            emulable = jnp.zeros((B,), bool)
+            for i, spec in enumerate(opspec.SYSCALLS):
+                hit = nr == spec.nr
+                action = jnp.where(hit, tr.pol_action[:, i], action)
+                pol_arg = jnp.where(hit, tr.pol_arg[:, i], pol_arg)
+                pol_slot = jnp.where(hit, jnp.int64(i), pol_slot)
+                if spec.emul:
+                    emulable = emulable | hit
+            pol_deny = m_svc & (action == POL_DENY)
+            pol_emul = m_svc & (action == POL_EMULATE)
+            pol_kill = m_svc & (action == POL_KILL)
+            # An EMULATE verdict on a guest-kernel-backed nr routes into the
+            # emulation branch (real fd-table service); on anything else it
+            # returns the policy constant, as it always did.  Both record the
+            # POL_EMULATE verdict and feed emul_count.
+            emul_route = pol_emul & emulable & en
+            pol_emul_const = pol_emul & ~(emulable & en)
+            svc_exec = m_svc & ((action == POL_ALLOW) | emul_route)
+        else:
+            svc_exec = m_svc
 
-    # Per-kind syscall masks generated from the spec's syscall rows; a new
-    # constant-returning syscall (K_CONST) is one table row, not a mask +
-    # a select row + a scalar branch.  Guest-kernel kinds split on the
-    # per-lane ``en`` gate: enabled lanes take the fd-table path
-    # (repro.emul), disabled lanes reproduce the legacy semantics exactly
-    # (openat/close keep their constant stubs, the rest fall through to
-    # -ENOSYS).
-    false_b = jnp.zeros((B,), bool)
-    sys_read = sys_write = sys_getpid = sys_exit = sys_sigret = false_b
-    sys_open = sys_close = sys_lseek = sys_dup = false_b
-    sys_fstat = sys_pipe = sys_rand = sys_ioctl = false_b
-    sys_const, known = false_b, false_b
-    const_val = zero
-    _EMUL_ONLY = {opspec.K_LSEEK: "lseek", opspec.K_DUP: "dup",
-                  opspec.K_FSTAT: "fstat", opspec.K_PIPE2: "pipe",
-                  opspec.K_GETRANDOM: "rand", opspec.K_IOCTL: "ioctl"}
-    emul_only_masks = {"lseek": sys_lseek, "dup": sys_dup, "fstat": sys_fstat,
-                       "pipe": sys_pipe, "rand": sys_rand, "ioctl": sys_ioctl}
-    for spec in opspec.SYSCALLS:
-        hit = svc_exec & (nr == spec.nr)
-        if spec.kind == opspec.K_IO_READ:
-            sys_read = sys_read | hit
-            known = known | hit
-        elif spec.kind == opspec.K_IO_WRITE:
-            sys_write = sys_write | hit
-            known = known | hit
-        elif spec.kind == opspec.K_GETPID:
-            sys_getpid = sys_getpid | hit
-            known = known | hit
-        elif spec.kind == opspec.K_EXIT:
-            sys_exit = sys_exit | hit
-            known = known | hit
-        elif spec.kind == opspec.K_SIGRETURN:
-            sys_sigret = sys_sigret | hit
-            known = known | hit
-        elif spec.kind in (opspec.K_OPENAT, opspec.K_CLOSE):
-            # enabled: real fd-table open/close; disabled: the historical
-            # constant stub (openat -> 3, close -> 0)
-            m = hit & en
-            if spec.kind == opspec.K_OPENAT:
-                sys_open = sys_open | m
-            else:
-                sys_close = sys_close | m
-            sys_const = sys_const | (hit & ~en)
-            const_val = jnp.where(hit & ~en, jnp.int64(spec.const), const_val)
-            known = known | hit
-        elif spec.kind in _EMUL_ONLY:
-            name = _EMUL_ONLY[spec.kind]
-            emul_only_masks[name] = emul_only_masks[name] | (hit & en)
-            known = known | (hit & en)  # disabled lanes: -ENOSYS, as before
-        else:  # K_CONST
-            sys_const = sys_const | hit
-            const_val = jnp.where(hit, jnp.int64(spec.const), const_val)
-            known = known | hit
-    sys_lseek, sys_dup, sys_fstat = (emul_only_masks["lseek"],
-                                     emul_only_masks["dup"],
-                                     emul_only_masks["fstat"])
-    sys_pipe, sys_rand, sys_ioctl = (emul_only_masks["pipe"],
-                                     emul_only_masks["rand"],
-                                     emul_only_masks["ioctl"])
-    sys_enosys = svc_exec & ~known
+        # Per-kind syscall masks generated from the spec's syscall rows; a new
+        # constant-returning syscall (K_CONST) is one table row, not a mask +
+        # a select row + a scalar branch.  Guest-kernel kinds split on the
+        # per-lane ``en`` gate: enabled lanes take the fd-table path
+        # (repro.emul), disabled lanes reproduce the legacy semantics exactly
+        # (openat/close keep their constant stubs, the rest fall through to
+        # -ENOSYS).
+        false_b = jnp.zeros((B,), bool)
+        sys_read = sys_write = sys_getpid = sys_exit = sys_sigret = false_b
+        sys_open = sys_close = sys_lseek = sys_dup = false_b
+        sys_fstat = sys_pipe = sys_rand = sys_ioctl = false_b
+        sys_const, known = false_b, false_b
+        const_val = zero
+        _EMUL_ONLY = {opspec.K_LSEEK: "lseek", opspec.K_DUP: "dup",
+                      opspec.K_FSTAT: "fstat", opspec.K_PIPE2: "pipe",
+                      opspec.K_GETRANDOM: "rand", opspec.K_IOCTL: "ioctl"}
+        emul_only_masks = {"lseek": sys_lseek, "dup": sys_dup,
+                           "fstat": sys_fstat, "pipe": sys_pipe,
+                           "rand": sys_rand, "ioctl": sys_ioctl}
+        for spec in opspec.SYSCALLS:
+            hit = svc_exec & (nr == spec.nr)
+            if spec.kind == opspec.K_IO_READ:
+                sys_read = sys_read | hit
+                known = known | hit
+            elif spec.kind == opspec.K_IO_WRITE:
+                sys_write = sys_write | hit
+                known = known | hit
+            elif spec.kind == opspec.K_GETPID:
+                sys_getpid = sys_getpid | hit
+                known = known | hit
+            elif spec.kind == opspec.K_EXIT:
+                sys_exit = sys_exit | hit
+                known = known | hit
+            elif spec.kind == opspec.K_SIGRETURN:
+                sys_sigret = sys_sigret | hit
+                known = known | hit
+            elif spec.kind in (opspec.K_OPENAT, opspec.K_CLOSE):
+                # enabled: real fd-table open/close; disabled: the historical
+                # constant stub (openat -> 3, close -> 0)
+                m = hit & en
+                if spec.kind == opspec.K_OPENAT:
+                    sys_open = sys_open | m
+                else:
+                    sys_close = sys_close | m
+                sys_const = sys_const | (hit & ~en)
+                const_val = jnp.where(hit & ~en, jnp.int64(spec.const),
+                                      const_val)
+                known = known | hit
+            elif spec.kind in _EMUL_ONLY:
+                name = _EMUL_ONLY[spec.kind]
+                emul_only_masks[name] = emul_only_masks[name] | (hit & en)
+                # disabled lanes: -ENOSYS, as before
+                known = known | (hit & en)
+            else:  # K_CONST
+                sys_const = sys_const | hit
+                const_val = jnp.where(hit, jnp.int64(spec.const), const_val)
+                known = known | hit
+        sys_lseek, sys_dup, sys_fstat = (emul_only_masks["lseek"],
+                                         emul_only_masks["dup"],
+                                         emul_only_masks["fstat"])
+        sys_pipe, sys_rand, sys_ioctl = (emul_only_masks["pipe"],
+                                         emul_only_masks["rand"],
+                                         emul_only_masks["ioctl"])
+        sys_enosys = svc_exec & ~known
 
-    io_buf, io_n = x1, x2
-    io_k = jnp.clip(io_n >> 3, 0, _MAX_IO_WORDS)
-    io_ok = (_mem_ok_v(io_buf) & (io_buf + io_n <= L.MEM_LIMIT)
-             & (io_n >= 0) & ((io_n & 7) == 0))
-    io_start = _widx_v(io_buf)
+        io_buf, io_n = x1, x2
+        io_k = jnp.clip(io_n >> 3, 0, _MAX_IO_WORDS)
+        io_ok = (_mem_ok_v(io_buf) & (io_buf + io_n <= L.MEM_LIMIT)
+                 & (io_n >= 0) & ((io_n & 7) == 0))
+        io_start = _widx_v(io_buf)
 
-    # First path word for openat lanes — the one-word namespace key.  Read
-    # from the pre-store memory (like v1/v2 above) behind a batch-uniform
-    # cond so the carry stays aliasable.
-    path_w = lax.cond(
-        jnp.any(sys_open),
-        lambda: mem_flat[lane_base + _widx_v(x1)],
-        lambda: jnp.zeros((B,), I64))
+        # First path word for openat lanes — the one-word namespace key.
+        # Read from the pre-store memory (like v1/v2 above) behind a
+        # batch-uniform cond so the carry stays aliasable.
+        path_w = lax.cond(
+            jnp.any(sys_open),
+            lambda: mem_flat[lane_base + _widx_v(x1)],
+            lambda: jnp.zeros((B,), I64))
 
     # -- guest-kernel service (control plane) -------------------------------
     # The whole fd-table step hides behind one batch-uniform cond: steps
@@ -501,53 +517,55 @@ def exec_lanes(fields, s: MachineState, tr: Optional[TraceState],
     # inside read/write, whose stream-vs-file routing the service decides)
     # pay a single jnp.any.  The neutral branch is bit-identical to the
     # service on such a batch.
-    emul_op = (sys_open | sys_close | sys_lseek | sys_dup | sys_fstat
-               | sys_pipe | sys_rand | sys_ioctl)
-    any_kern = jnp.any(emul_op | ((sys_read | sys_write) & en))
-    eff = lax.cond(
-        any_kern,
-        lambda: emul_engine.service(
-            s, en=en, x0=x0, x1=x1, x2=x2, path_w=path_w,
-            io_ok=io_ok, io_n=io_n,
-            sys_open=sys_open, sys_close=sys_close, sys_lseek=sys_lseek,
-            sys_dup=sys_dup, sys_fstat=sys_fstat, sys_pipe=sys_pipe,
-            sys_rand=sys_rand, sys_ioctl=sys_ioctl,
-            sys_read=sys_read, sys_write=sys_write),
-        lambda: emul_engine.neutral(s, sys_read, sys_write))
-    io_do = (eff.rd_stream | eff.wr_stream) & io_ok
+    with jax.named_scope("emul"):
+        emul_op = (sys_open | sys_close | sys_lseek | sys_dup | sys_fstat
+                   | sys_pipe | sys_rand | sys_ioctl)
+        any_kern = jnp.any(emul_op | ((sys_read | sys_write) & en))
+        eff = lax.cond(
+            any_kern,
+            lambda: emul_engine.service(
+                s, en=en, x0=x0, x1=x1, x2=x2, path_w=path_w,
+                io_ok=io_ok, io_n=io_n,
+                sys_open=sys_open, sys_close=sys_close, sys_lseek=sys_lseek,
+                sys_dup=sys_dup, sys_fstat=sys_fstat, sys_pipe=sys_pipe,
+                sys_rand=sys_rand, sys_ioctl=sys_ioctl,
+                sys_read=sys_read, sys_write=sys_write),
+            lambda: emul_engine.neutral(s, sys_read, sys_write))
+        io_do = (eff.rd_stream | eff.wr_stream) & io_ok
 
-    virt = in_pt & (s.virt_getpid != 0)
-    svc_x0 = jnp.select(
-        [eff.rd_stream | eff.wr_stream,
-         eff.is_ret,
-         sys_getpid,
-         sys_const,
-         sys_enosys],
-        [jnp.where(io_ok, io_n, jnp.int64(-14)),
-         eff.ret,
-         jnp.where(virt, jnp.int64(L.VIRT_PID), s.pid),
-         const_val,
-         jnp.full((B,), -38, I64)],
-        zero)
-    svc_x0_en = svc_exec & ~(sys_exit | sys_sigret)
-    if traced:
-        # DENY returns -errno, non-routable EMULATE returns the policy
-        # constant; both skip the kernel branch and fall through to pc+4.
-        # Routed EMULATE lanes already hold their emulated return in
-        # svc_x0 (eff.ret).
-        svc_x0 = jnp.select([pol_deny, pol_emul_const],
-                            [-pol_arg, pol_arg], svc_x0)
-        svc_x0_en = svc_x0_en | pol_deny | pol_emul_const
+    with jax.named_scope("syscall"):
+        virt = in_pt & (s.virt_getpid != 0)
+        svc_x0 = jnp.select(
+            [eff.rd_stream | eff.wr_stream,
+             eff.is_ret,
+             sys_getpid,
+             sys_const,
+             sys_enosys],
+            [jnp.where(io_ok, io_n, jnp.int64(-14)),
+             eff.ret,
+             jnp.where(virt, jnp.int64(L.VIRT_PID), s.pid),
+             const_val,
+             jnp.full((B,), -38, I64)],
+            zero)
+        svc_x0_en = svc_exec & ~(sys_exit | sys_sigret)
+        if traced:
+            # DENY returns -errno, non-routable EMULATE returns the policy
+            # constant; both skip the kernel branch and fall through to pc+4.
+            # Routed EMULATE lanes already hold their emulated return in
+            # svc_x0 (eff.ret).
+            svc_x0 = jnp.select([pol_deny, pol_emul_const],
+                                [-pol_arg, pol_arg], svc_x0)
+            svc_x0_en = svc_x0_en | pol_deny | pol_emul_const
 
-    # -- signal delivery / sigreturn (static 34-word frame window) -----------
-    # ``dlv`` is the P_TRAP pc-class mask from the spec gathers above; the
-    # signal number rides the SIGNO column (garbage on non-trap lanes, but
-    # only consumed under can_sig).
-    can_sig = dlv & (s.sig_handler != 0) & (s.in_signal == 0)
-    trap_fail = dlv & ~can_sig
-    signo = tbl.SIGNO[op]
-    frame_out = jnp.concatenate(
-        [regs0, sp0[:, None], pc0[:, None], nzcv0[:, None]], axis=1)
+        # -- signal delivery / sigreturn (static 34-word frame window) -------
+        # ``dlv`` is the P_TRAP pc-class mask from the spec gathers above; the
+        # signal number rides the SIGNO column (garbage on non-trap lanes, but
+        # only consumed under can_sig).
+        can_sig = dlv & (s.sig_handler != 0) & (s.in_signal == 0)
+        trap_fail = dlv & ~can_sig
+        signo = tbl.SIGNO[op]
+        frame_out = jnp.concatenate(
+            [regs0, sp0[:, None], pc0[:, None], nzcv0[:, None]], axis=1)
 
     # -- memory writes -------------------------------------------------------
     # One merged scatter for both store slots.  Disabled / faulting writes
@@ -556,38 +574,42 @@ def exec_lanes(fields, s: MachineState, tr: Optional[TraceState],
     # When a pair store clip-aliases (base in range, base+8 not), slot 2 is
     # dropped, exactly matching the scalar sequential-store semantics; when
     # both slots land, their indices are distinct by construction.
-    oob = jnp.int64(L.MEM_WORDS * B)
-    park = oob + jnp.arange(2 * B, dtype=I64)  # distinct OOB slots per entry
-    st_byte = c(memc, opspec.M_STORE_BYTE)
-    st1_en = (st_single | st_pair | st_byte) & ok1
-    st2_en = st_pair & ok2
-    st_idx = jnp.concatenate([jnp.where(st1_en, lane_base + g1, park[:B]),
-                              jnp.where(st2_en, lane_base + g2, park[B:])])
-    st_val = jnp.concatenate([jnp.where(byte_op, strb_word, rd_rr), rm_rr])
-    # indices are genuinely unique: live pair slots differ by construction,
-    # parked slots each get their own out-of-bounds id (dropped)
-    mem = mem_flat.at[st_idx].set(st_val, mode="drop",
-                                  unique_indices=True).reshape(B, L.MEM_WORDS)
+    with jax.named_scope("mem"):
+        oob = jnp.int64(L.MEM_WORDS * B)
+        # distinct OOB slots per entry
+        park = oob + jnp.arange(2 * B, dtype=I64)
+        st_byte = c(memc, opspec.M_STORE_BYTE)
+        st1_en = (st_single | st_pair | st_byte) & ok1
+        st2_en = st_pair & ok2
+        st_idx = jnp.concatenate([jnp.where(st1_en, lane_base + g1, park[:B]),
+                                  jnp.where(st2_en, lane_base + g2, park[B:])])
+        st_val = jnp.concatenate([jnp.where(byte_op, strb_word, rd_rr), rm_rr])
+        # indices are genuinely unique: live pair slots differ by construction,
+        # parked slots each get their own out-of-bounds id (dropped)
+        mem = mem_flat.at[st_idx].set(st_val, mode="drop",
+                                      unique_indices=True).reshape(
+                                          B, L.MEM_WORDS)
 
-    # Sigframe push is rare (only brk/illegal on a lane with a handler):
-    # keep the 34-word window write behind a batch-uniform cond.
-    def push_frames(mm):
-        cur = mm[:, _SIGFRAME_IDX:_SIGFRAME_IDX + SIGFRAME_WORDS]
-        return mm.at[:, _SIGFRAME_IDX:_SIGFRAME_IDX + SIGFRAME_WORDS].set(
-            jnp.where(can_sig[:, None], frame_out, cur))
+        # Sigframe push is rare (only brk/illegal on a lane with a handler):
+        # keep the 34-word window write behind a batch-uniform cond.
+        def push_frames(mm):
+            cur = mm[:, _SIGFRAME_IDX:_SIGFRAME_IDX + SIGFRAME_WORDS]
+            return mm.at[:, _SIGFRAME_IDX:_SIGFRAME_IDX + SIGFRAME_WORDS].set(
+                jnp.where(can_sig[:, None], frame_out, cur))
 
-    mem = lax.cond(jnp.any(can_sig), push_frames, lambda mm: mm, mem)
+        mem = lax.cond(jnp.any(can_sig), push_frames, lambda mm: mm, mem)
 
     # fstat statbuf / pipe2 fd-pair result words: <= 6 words fleet-wide,
     # parked out-of-bounds + dropped when masked, behind the same
     # batch-uniform cond discipline as the sigframe push.
-    def emul_result_words(mm):
-        return mm.reshape(-1).at[eff.scat_idx].set(
-            eff.scat_val, mode="drop",
-            unique_indices=True).reshape(B, L.MEM_WORDS)
+    with jax.named_scope("emul"):
+        def emul_result_words(mm):
+            return mm.reshape(-1).at[eff.scat_idx].set(
+                eff.scat_val, mode="drop",
+                unique_indices=True).reshape(B, L.MEM_WORDS)
 
-    mem = lax.cond(jnp.any(eff.scat_do), emul_result_words,
-                   lambda mm: mm, mem)
+        mem = lax.cond(jnp.any(eff.scat_do), emul_result_words,
+                       lambda mm: mm, mem)
 
     # Syscall I/O fill/sum.  Typically only a lane or two is inside
     # read/write on any given step, so iterate over the io lanes (a bare
@@ -597,59 +619,62 @@ def exec_lanes(fields, s: MachineState, tr: Optional[TraceState],
     # slices of its own region.  Cost is proportional to the words actually
     # transferred, not fleet-width x window (a [B, W] masked scatter per
     # event throttled an 80-lane mixed census to 0.5x scalar).
-    W_IO = 512
-    _woff = jnp.arange(W_IO, dtype=I64)
+    with jax.named_scope("io_mover"):
+        W_IO = 512
+        _woff = jnp.arange(W_IO, dtype=I64)
 
-    def io_lane_body(carry):
-        mf, sums, rem = carry
-        b = jnp.argmax(rem)               # next io lane
-        k_b = io_k[b]
-        start_b = lane_base[b] + io_start[b]
-        rd_b = sys_read[b]
-        off_b = s.in_off[b]
+        def io_lane_body(carry):
+            mf, sums, rem = carry
+            b = jnp.argmax(rem)               # next io lane
+            k_b = io_k[b]
+            start_b = lane_base[b] + io_start[b]
+            rd_b = sys_read[b]
+            off_b = s.in_off[b]
 
-        def win_body(c, inner):
-            mf2, acc = inner
-            base = start_b + c * W_IO     # dynamic_slice clamps at the end
-            # conditional read (vacuously true: c < nwin inside the loop):
-            # as at step level, a bare read whose value outlives the update
-            # below would make XLA copy the whole flat memory every window;
-            # branch-wrapped reads keep it aliasable
-            cur = lax.cond(
-                c < nwin,
-                lambda: lax.dynamic_slice(mf2, (base,), (W_IO,)),
-                lambda: jnp.zeros((W_IO,), I64))
-            pos = jnp.clip(base, 0, B * L.MEM_WORDS - W_IO) + _woff
-            within = (pos >= start_b + c * W_IO) & (pos < start_b + k_b)
-            fill = off_b + (pos - start_b) * 8
-            new = jnp.where(within & rd_b, fill, cur)
-            mf2 = lax.dynamic_update_slice(mf2, new, (base,))
-            acc = acc + jnp.sum(jnp.where(within & ~rd_b, cur, jnp.int64(0)))
-            return mf2, acc
+            def win_body(c, inner):
+                mf2, acc = inner
+                base = start_b + c * W_IO     # dynamic_slice clamps at the end
+                # conditional read (vacuously true: c < nwin inside the loop):
+                # as at step level, a bare read whose value outlives the update
+                # below would make XLA copy the whole flat memory every window;
+                # branch-wrapped reads keep it aliasable
+                cur = lax.cond(
+                    c < nwin,
+                    lambda: lax.dynamic_slice(mf2, (base,), (W_IO,)),
+                    lambda: jnp.zeros((W_IO,), I64))
+                pos = jnp.clip(base, 0, B * L.MEM_WORDS - W_IO) + _woff
+                within = (pos >= start_b + c * W_IO) & (pos < start_b + k_b)
+                fill = off_b + (pos - start_b) * 8
+                new = jnp.where(within & rd_b, fill, cur)
+                mf2 = lax.dynamic_update_slice(mf2, new, (base,))
+                acc = acc + jnp.sum(jnp.where(within & ~rd_b, cur,
+                                              jnp.int64(0)))
+                return mf2, acc
 
-        nwin = (k_b + W_IO - 1) // W_IO
-        mf, acc = lax.fori_loop(jnp.int64(0), nwin, win_body,
-                                (mf, jnp.int64(0)))
-        sums = sums.at[b].set(acc)
-        rem = rem.at[b].set(False)
-        return mf, sums, rem
+            nwin = (k_b + W_IO - 1) // W_IO
+            mf, acc = lax.fori_loop(jnp.int64(0), nwin, win_body,
+                                    (mf, jnp.int64(0)))
+            sums = sums.at[b].set(acc)
+            rem = rem.at[b].set(False)
+            return mf, sums, rem
 
-    mem_io, io_sum, _ = lax.while_loop(
-        lambda c: jnp.any(c[2]), io_lane_body,
-        (mem.reshape(-1), zero, io_do))
-    mem = mem_io.reshape(B, L.MEM_WORDS)
+        mem_io, io_sum, _ = lax.while_loop(
+            lambda c: jnp.any(c[2]), io_lane_body,
+            (mem.reshape(-1), zero, io_do))
+        mem = mem_io.reshape(B, L.MEM_WORDS)
 
     # Guest-kernel bulk data (file/pipe/proc reads+writes, getrandom
     # fills): the same bare-while-loop discipline over the (memory,
     # inode-data) flat planes — zero iterations when no lane moves words.
-    proc_flat = lax.cond(
-        jnp.any(eff.src_is_proc),
-        lambda: emul_engine.proc_rows(s).reshape(-1),
-        lambda: jnp.zeros((B * L.PROC_WORDS,), I64))
-    mem_fio, ino_flat = emul_engine.run_data_loop(
-        mem.reshape(-1), eff.kern.ino_data.reshape(-1), proc_flat, eff)
-    mem = mem_fio.reshape(B, L.MEM_WORDS)
-    k_ino_data = ino_flat.reshape(B, L.MAX_INODES * L.FILE_WORDS)
+    with jax.named_scope("emul"):
+        proc_flat = lax.cond(
+            jnp.any(eff.src_is_proc),
+            lambda: emul_engine.proc_rows(s).reshape(-1),
+            lambda: jnp.zeros((B * L.PROC_WORDS,), I64))
+        mem_fio, ino_flat = emul_engine.run_data_loop(
+            mem.reshape(-1), eff.kern.ino_data.reshape(-1), proc_flat, eff)
+        mem = mem_fio.reshape(B, L.MEM_WORDS)
+        k_ino_data = ino_flat.reshape(B, L.MAX_INODES * L.FILE_WORDS)
 
     # Sigreturn frame read — from the FINAL memory, after all writes.  A
     # sigreturn lane performs no store/push/I-O in the same step, so its row
@@ -658,36 +683,39 @@ def exec_lanes(fields, s: MachineState, tr: Optional[TraceState],
     # writer, which would force XLA to copy the whole [B, MEM_WORDS] buffer
     # every step (measured ~15x slowdown).  Rare op => batch-uniform cond;
     # the zeros fallback is safe: every consumer is masked by sys_sigret.
-    frame_in = lax.cond(
-        jnp.any(sys_sigret),
-        lambda: mem[:, _SIGFRAME_IDX:_SIGFRAME_IDX + SIGFRAME_WORDS],
-        lambda: jnp.zeros((B, SIGFRAME_WORDS), I64))
+    with jax.named_scope("mem"):
+        frame_in = lax.cond(
+            jnp.any(sys_sigret),
+            lambda: mem[:, _SIGFRAME_IDX:_SIGFRAME_IDX + SIGFRAME_WORDS],
+            lambda: jnp.zeros((B, SIGFRAME_WORDS), I64))
 
     # -- register writes (slot order mirrors the scalar handler order) ------
-    col = jnp.arange(31)[None, :]
+    with jax.named_scope("regs"):
+        col = jnp.arange(31)[None, :]
 
-    def apply_slot(regs, en, idxv, val, sp, sp_ok):
-        hit = en[:, None] & (idxv[:, None] == col)  # idx 31 never matches
-        regs = jnp.where(hit, val[:, None], regs)
-        sp = jnp.where(en & sp_ok & (idxv == 31), val, sp)
-        return regs, sp
+        def apply_slot(regs, en, idxv, val, sp, sp_ok):
+            hit = en[:, None] & (idxv[:, None] == col)  # idx 31 never matches
+            regs = jnp.where(hit, val[:, None], regs)
+            sp = jnp.where(en & sp_ok & (idxv == 31), val, sp)
+            return regs, sp
 
-    regs, sp = apply_slot(regs0, slotA_en, slotA_idx, slotA_val, sp0, slotA_sp)
-    regs, sp = apply_slot(regs, ld_pair, rm, ld2, sp,
-                          jnp.zeros((B,), bool))
-    wb = tbl.WB_BASE[op] & act
-    regs, sp = apply_slot(regs, wb, rn, rn_rsp + imm, sp,
-                          jnp.ones((B,), bool))
+        regs, sp = apply_slot(regs0, slotA_en, slotA_idx, slotA_val, sp0,
+                              slotA_sp)
+        regs, sp = apply_slot(regs, ld_pair, rm, ld2, sp,
+                              jnp.zeros((B,), bool))
+        wb = tbl.WB_BASE[op] & act
+        regs, sp = apply_slot(regs, wb, rn, rn_rsp + imm, sp,
+                              jnp.ones((B,), bool))
 
-    regs = regs.at[:, 0].set(jnp.where(svc_x0_en, svc_x0, regs[:, 0]))
-    regs = regs.at[:, 0].set(jnp.where(can_sig, signo, regs[:, 0]))
-    regs = regs.at[:, 1].set(jnp.where(can_sig,
-                                       jnp.int64(L.SIGFRAME), regs[:, 1]))
-    sp = jnp.where(can_sig, jnp.int64(L.SIGSTACK_TOP), sp)
+        regs = regs.at[:, 0].set(jnp.where(svc_x0_en, svc_x0, regs[:, 0]))
+        regs = regs.at[:, 0].set(jnp.where(can_sig, signo, regs[:, 0]))
+        regs = regs.at[:, 1].set(jnp.where(can_sig,
+                                           jnp.int64(L.SIGFRAME), regs[:, 1]))
+        sp = jnp.where(can_sig, jnp.int64(L.SIGSTACK_TOP), sp)
 
-    regs = jnp.where(sys_sigret[:, None], frame_in[:, :31], regs)
-    sp = jnp.where(sys_sigret, frame_in[:, 31], sp)
-    nzcv = jnp.where(sys_sigret, frame_in[:, 33], nzcv)
+        regs = jnp.where(sys_sigret[:, None], frame_in[:, :31], regs)
+        sp = jnp.where(sys_sigret, frame_in[:, 31], sp)
+        nzcv = jnp.where(sys_sigret, frame_in[:, 33], nzcv)
 
     # -- program counter -----------------------------------------------------
     br_target = pc0 + imm
@@ -758,58 +786,59 @@ def exec_lanes(fields, s: MachineState, tr: Optional[TraceState],
     emul_served = s.emul_served + jnp.where(eff.served, jnp.int64(1), zero)
 
     # -- trace record append (traced path only) ------------------------------
-    if traced:
-        cap = tr.buf.shape[2]
+    with jax.named_scope("trace_ring"):
+        if traced:
+            cap = tr.buf.shape[2]
 
-        # Svc steps are rare (one in tens of steps), so the whole record
-        # computation + 8-word row scatter + histogram bump hide behind the
-        # same batch-uniform cond as the policy lookup (like the sigframe
-        # push); parked out-of-bounds indices drop the non-svc lanes.
-        def append(operand):
-            buf, hist = operand
-            ret = jnp.select(
-                [pol_deny, pol_emul_const, pol_kill, sys_exit, sys_sigret],
-                [-pol_arg, pol_arg, zero, x0, frame_in[:, 0]],
-                svc_x0)  # routed EMULATE lanes: svc_x0 == the emulated ret
-            verdict = jnp.select(
-                [pol_deny, pol_emul, pol_kill, sys_enosys],
-                [jnp.full((B,), POL_DENY, I64),
-                 jnp.full((B,), POL_EMULATE, I64),
-                 jnp.full((B,), POL_KILL, I64),
-                 jnp.full((B,), VERDICT_UNKNOWN, I64)],
-                zero)  # POL_ALLOW
-            flat = buf.reshape(B * 2 * cap, REC_WORDS)
-            pos = (lanes * (2 * cap)).astype(I64) + tr.hot * cap \
-                + (tr.count - tr.base) % cap
-            idx = jnp.where(m_svc, pos,
-                            jnp.int64(B * 2 * cap) + lanes.astype(I64))
-            rows = jnp.stack([s.icount, pc0, nr, x0, x1, x2, ret, verdict],
-                             axis=1)
-            buf = flat.at[idx].set(rows, mode="drop",
-                                   unique_indices=True).reshape(B, 2, cap,
-                                                                REC_WORDS)
-            hflat = hist.reshape(B * N_POLICY_SLOTS * N_VERDICTS)
-            hpos = lanes.astype(I64) * (N_POLICY_SLOTS * N_VERDICTS) \
-                + pol_slot * N_VERDICTS + verdict
-            hidx = jnp.where(m_svc, hpos,
-                             jnp.int64(B * N_POLICY_SLOTS * N_VERDICTS)
-                             + lanes.astype(I64))
-            hist = hflat.at[hidx].add(jnp.int64(1), mode="drop",
-                                      unique_indices=True).reshape(
-                                          B, N_POLICY_SLOTS, N_VERDICTS)
-            return buf, hist
+            # Svc steps are rare (one in tens of steps), so the whole record
+            # computation + 8-word row scatter + histogram bump hide behind the
+            # same batch-uniform cond as the policy lookup (like the sigframe
+            # push); parked out-of-bounds indices drop the non-svc lanes.
+            def append(operand):
+                buf, hist = operand
+                ret = jnp.select(
+                    [pol_deny, pol_emul_const, pol_kill, sys_exit, sys_sigret],
+                    [-pol_arg, pol_arg, zero, x0, frame_in[:, 0]],
+                    svc_x0)  # routed EMULATE lanes: svc_x0 == the emulated ret
+                verdict = jnp.select(
+                    [pol_deny, pol_emul, pol_kill, sys_enosys],
+                    [jnp.full((B,), POL_DENY, I64),
+                     jnp.full((B,), POL_EMULATE, I64),
+                     jnp.full((B,), POL_KILL, I64),
+                     jnp.full((B,), VERDICT_UNKNOWN, I64)],
+                    zero)  # POL_ALLOW
+                flat = buf.reshape(B * 2 * cap, REC_WORDS)
+                pos = (lanes * (2 * cap)).astype(I64) + tr.hot * cap \
+                    + (tr.count - tr.base) % cap
+                idx = jnp.where(m_svc, pos,
+                                jnp.int64(B * 2 * cap) + lanes.astype(I64))
+                rows = jnp.stack([s.icount, pc0, nr, x0, x1, x2, ret, verdict],
+                                 axis=1)
+                buf = flat.at[idx].set(rows, mode="drop",
+                                       unique_indices=True).reshape(B, 2, cap,
+                                                                    REC_WORDS)
+                hflat = hist.reshape(B * N_POLICY_SLOTS * N_VERDICTS)
+                hpos = lanes.astype(I64) * (N_POLICY_SLOTS * N_VERDICTS) \
+                    + pol_slot * N_VERDICTS + verdict
+                hidx = jnp.where(m_svc, hpos,
+                                 jnp.int64(B * N_POLICY_SLOTS * N_VERDICTS)
+                                 + lanes.astype(I64))
+                hist = hflat.at[hidx].add(jnp.int64(1), mode="drop",
+                                          unique_indices=True).reshape(
+                                              B, N_POLICY_SLOTS, N_VERDICTS)
+                return buf, hist
 
-        buf, hist = lax.cond(any_svc, append, lambda op: op,
-                             (tr.buf, tr.hist))
-        one = jnp.int64(1)
-        tr = tr._replace(
-            buf=buf, hist=hist,
-            count=tr.count + jnp.where(m_svc, one, zero),
-            # the scheduler's budget feed: plain masked adds, cheap enough
-            # to live outside the any_svc cond
-            deny_count=tr.deny_count + jnp.where(pol_deny, one, zero),
-            emul_count=tr.emul_count + jnp.where(pol_emul, one, zero),
-            kill_count=tr.kill_count + jnp.where(pol_kill, one, zero))
+            buf, hist = lax.cond(any_svc, append, lambda op: op,
+                                 (tr.buf, tr.hist))
+            one = jnp.int64(1)
+            tr = tr._replace(
+                buf=buf, hist=hist,
+                count=tr.count + jnp.where(m_svc, one, zero),
+                # the scheduler's budget feed: plain masked adds, cheap enough
+                # to live outside the any_svc cond
+                deny_count=tr.deny_count + jnp.where(pol_deny, one, zero),
+                emul_count=tr.emul_count + jnp.where(pol_emul, one, zero),
+                kill_count=tr.kill_count + jnp.where(pol_kill, one, zero))
 
     kern = eff.kern
     return s._replace(

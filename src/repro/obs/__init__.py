@@ -25,12 +25,14 @@ from typing import Optional
 
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                make_sink, now)
-from repro.obs.profiler import NULL_TIMER, PHASES, PhaseProfiler
+from repro.obs.profiler import (NULL_TIMER, PHASES, PhaseProfiler,
+                                 step_annotation)
 from repro.obs.spans import SpanTracker
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "ObsHub",
     "PHASES", "PhaseProfiler", "SpanTracker", "make_sink", "now", "phase",
+    "step_annotation",
 ]
 
 

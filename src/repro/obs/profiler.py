@@ -17,19 +17,34 @@ stage of ``FleetServer.step()`` runs inside a named phase —
     retry_backoff   chaos retry sleeps
     obs_snapshot    sink snapshot writes (self-observation, priced too)
 
+A phase may open named child phases, written ``parent/child``, inside
+itself: they split admission, dispatch and harvest by cause (eager
+initial states vs the scatter, enqueue vs the wait for the chip,
+readback vs per-lane unstacking vs publishing).  Children report the
+same fields as any phase; coverage counts top-level phases only, so a
+child's time is never counted twice.
+
+Every timer also opens a ``jax.profiler.TraceAnnotation`` named
+``fleet.<phase>`` while a profiler trace is active, so the same spans
+sit on the device trace's clock; without an active trace no annotation
+(and no name for one) is built.  The obs clock stays the source of
+every total.
+
 Timings come from :func:`repro.obs.metrics.now` (monotonic) and land in
 one labelled histogram (``server_phase_seconds{phase=...}``) plus a
 plain totals dict, so ``breakdown()`` can report both percentiles and
 the coverage ratio — the share of measured generation time the phases
 explain, which ``benchmarks/obs_overhead.py`` requires to be >= 90%.
 
-Phases never nest on the same profiler: the timer is a plain class
-(not a generator contextmanager) to keep per-phase overhead at two
-clock reads and two dict ops.
+The timer is a plain class (not a generator contextmanager) to keep
+per-phase overhead at two clock reads, one trace-state check and a few
+dict and list ops.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Dict, List, Optional, Tuple
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.obs.metrics import Histogram, MetricsRegistry, now
 
@@ -37,27 +52,42 @@ PHASES = (
     "sched_pass", "rebucket", "admission", "dispatch", "device_sync",
     "harvest", "stream_flush", "journal_append", "snapshot_write",
     "rollback_verify", "retry_backoff", "obs_snapshot",
+    # children: eager per-request initial states, image-table rows, the
+    # padded admission/restore scatter
+    "admission/initial_state", "admission/image_row", "admission/scatter",
+    # span and flip enqueues vs the blocking wait for the sub-span
+    "dispatch/enqueue", "dispatch/device_wait",
+    # host transfers, C3 diagnosis and recycling, per-lane unstack + halt
+    # patch, stream pop / histograms / charges / FleetResult
+    "harvest/readback", "harvest/c3", "harvest/unstack", "harvest/publish",
 )
+TRACE_PREFIX = "fleet."
 
 
 class _PhaseTimer:
     """``with prof.phase("harvest"):`` — records on exit, even on error."""
 
-    __slots__ = ("_prof", "_name", "_t0")
+    __slots__ = ("_prof", "_name", "_t0", "_annot")
 
     def __init__(self, prof: "PhaseProfiler", name: str):
         self._prof = prof
         self._name = name
 
     def __enter__(self):
+        self._annot = None
+        if TraceAnnotation.is_enabled():      # a profiler trace is active
+            self._annot = TraceAnnotation(TRACE_PREFIX + self._name)
+            self._annot.__enter__()
         self._t0 = now()
-        self._prof._inflight = self._name
-        self._prof._inflight_t0 = self._t0
+        self._prof._inflight.append((self._name, self._t0))
         return self
 
     def __exit__(self, *exc):
-        self._prof._inflight = None
-        self._prof.record(self._name, now() - self._t0)
+        dt = now() - self._t0
+        self._prof._inflight.pop()
+        self._prof.record(self._name, dt)
+        if self._annot is not None:
+            self._annot.__exit__(*exc)
         return False
 
 
@@ -76,6 +106,15 @@ class _NullTimer:
 NULL_TIMER = _NullTimer()
 
 
+def step_annotation(step_num: int, **meta):
+    """``jax.profiler.StepTraceAnnotation("fleet.generation")`` around one
+    generation while a profiler trace is active, else the shared no-op."""
+    if not TraceAnnotation.is_enabled():
+        return NULL_TIMER
+    return StepTraceAnnotation(TRACE_PREFIX + "generation",
+                               step_num=step_num, **meta)
+
+
 class PhaseProfiler:
     def __init__(self, registry: MetricsRegistry):
         self.registry = registry
@@ -87,12 +126,12 @@ class PhaseProfiler:
         self.counts: Dict[str, int] = {}
         self.gen_total = 0.0
         self.gen_count = 0
-        # the phase timer currently open, if any: exports taken from
-        # *inside* a phase (journal watermarks, snapshot writes) credit
-        # it with its elapsed-so-far time so counts stay exactly
-        # monotone across a crash-recovery cut
-        self._inflight: Optional[str] = None
-        self._inflight_t0 = 0.0
+        # the phase timers currently open, outermost first, with their
+        # start times: exports taken from *inside* a phase (journal
+        # watermarks, snapshot writes) credit each of them with its
+        # elapsed-so-far time so counts stay exactly monotone across a
+        # crash-recovery cut
+        self._inflight: List[Tuple[str, float]] = []
 
     # -- recording ------------------------------------------------------
     def phase(self, name: str) -> _PhaseTimer:
@@ -124,7 +163,8 @@ class PhaseProfiler:
                 "share": (self.totals[name] / self.gen_total
                           if self.gen_total else 0.0),
             }
-        covered = sum(self.totals.values())
+        # children sit inside their parent: top-level phases only
+        covered = sum(v for k, v in self.totals.items() if "/" not in k)
         return {
             "phases": phases,
             "generation": {"count": self.gen_count, "total_s": self.gen_total,
@@ -141,11 +181,10 @@ class PhaseProfiler:
     def export(self) -> dict:
         d = {"totals": dict(self.totals), "counts": dict(self.counts),
              "gen_total": self.gen_total, "gen_count": self.gen_count}
-        if self._inflight is not None:
-            name = self._inflight
+        t = now()
+        for name, t0 in self._inflight:
             d["counts"][name] = d["counts"].get(name, 0) + 1
-            d["totals"][name] = (d["totals"].get(name, 0.0)
-                                 + (now() - self._inflight_t0))
+            d["totals"][name] = d["totals"].get(name, 0.0) + (t - t0)
         return d
 
     def restore(self, d: Optional[dict]) -> None:

@@ -82,6 +82,7 @@ from repro.core.runtime import (FleetImageTable, ImageTableFull, Mechanism,
 from repro.obs import ObsHub
 from repro.obs import now as obs_now
 from repro.obs import phase as obs_phase
+from repro.obs import step_annotation
 from repro.sched.scheduler import PolicyScheduler
 from repro.trace import policy as trace_policy
 from repro.trace import recorder as trace_recorder
@@ -817,7 +818,9 @@ class FleetServer:
         r_traces: List[F.TraceState] = []
         for req in self._readmit:                # slot already owned
             lanes_idx.append(phys_of[req.slot])
-            lanes.append(initial_state(req.pp, fuel=req.fuel, regs=req.regs))
+            with self._phase("admission/initial_state"):
+                lanes.append(initial_state(req.pp, fuel=req.fuel,
+                                           regs=req.regs))
             pols.append(req.policy)
             self._ids[req.slot] = req.row
             self._fuel[req.slot] = req.fuel
@@ -843,7 +846,8 @@ class FleetServer:
                 cand = pending[0]
                 if cand.checkpoint is None:
                     try:
-                        cand.row = self.table.admit(cand.pp)
+                        with self._phase("admission/image_row"):
+                            cand.row = self.table.admit(cand.pp)
                     except ImageTableFull:
                         # table transiently full: rows free as lanes
                         # finish.  Without a scheduler the FIFO head
@@ -891,72 +895,82 @@ class FleetServer:
                 r_traces.append(tr)
                 continue
             lanes_idx.append(p)
-            lanes.append(initial_state(req.pp, fuel=req.fuel, regs=req.regs))
+            with self._phase("admission/initial_state"):
+                lanes.append(initial_state(req.pp, fuel=req.fuel,
+                                           regs=req.regs))
             pols.append(req.policy)
         if lanes_idx:
-            self._prev_icount[lanes_idx] = 0     # admitted lanes restart
-            pad = self._W - len(lanes_idx)       # park padding out of range
-            lanes_idx += [self._W + i for i in range(pad)]
-            lanes += [self._pad_state] * pad
-            pols += [None] * pad
-            if self._trace is None:
-                self._states = F.admit_lanes(self._states, lanes_idx, lanes)
-            else:
-                self._states, self._trace = F.admit_lanes(
-                    self._states, lanes_idx, lanes, trace=self._trace,
-                    policies=pols)
+            with self._phase("admission/scatter"):
+                self._prev_icount[lanes_idx] = 0  # admitted lanes restart
+                pad = self._W - len(lanes_idx)    # park padding out of range
+                lanes_idx += [self._W + i for i in range(pad)]
+                lanes += [self._pad_state] * pad
+                pols += [None] * pad
+                if self._trace is None:
+                    self._states = F.admit_lanes(self._states, lanes_idx,
+                                                 lanes)
+                else:
+                    self._states, self._trace = F.admit_lanes(
+                        self._states, lanes_idx, lanes, trace=self._trace,
+                        policies=pols)
         if r_idx:
-            pad = self._W - len(r_idx)
-            r_idx += [self._W + i for i in range(pad)]
-            r_states += [self._pad_lane] * pad
-            if self._trace is None:
-                self._states = F.restore_lanes(self._states, r_idx, r_states)
-            else:
-                r_traces += [self._pad_trace_lane] * pad
-                self._states, self._trace = F.restore_lanes(
-                    self._states, r_idx, r_states, trace=self._trace,
-                    lane_traces=r_traces)
+            with self._phase("admission/scatter"):
+                pad = self._W - len(r_idx)
+                r_idx += [self._W + i for i in range(pad)]
+                r_states += [self._pad_lane] * pad
+                if self._trace is None:
+                    self._states = F.restore_lanes(self._states, r_idx,
+                                                   r_states)
+                else:
+                    r_traces += [self._pad_trace_lane] * pad
+                    self._states, self._trace = F.restore_lanes(
+                        self._states, r_idx, r_states, trace=self._trace,
+                        lane_traces=r_traces)
 
     def _harvest(self) -> List[FleetResult]:
-        halted = np.asarray(self._states.halted)
-        icount = np.asarray(self._states.icount)
-        # occupancy ledger: lane-steps actually executed this generation vs
-        # the lane-steps the dispatch paid for (bucket width x chunks run)
-        delta = icount - self._prev_icount
-        chunks_run = int(-(-int(delta.max()) // self.chunk)) if delta.max() \
-            else 0
-        self.dispatched_steps += self._W * chunks_run * self.chunk
-        self.executed_steps += int(delta.sum())
-        self._prev_icount = icount.copy()
-        patched = F.finish_halt_codes(halted, icount, self._fuel[self._order])
-        done = patched != M.RUNNING
-        if done.any():  # one transfer per field, only when publishing
-            enosys = np.asarray(self._states.enosys_count)
-            emul_served = np.asarray(self._states.emul_served)
-            if self._trace is not None:
-                if self._stream is None:
-                    # classic mode decodes rings from the carry; streamed
-                    # lanes publish from the TraceStream, so the (large)
-                    # double-buffer transfer is skipped entirely
-                    trace_buf = np.asarray(self._trace.buf)
-                trace_cnt = np.asarray(self._trace.count)
-                trace_hist = np.asarray(self._trace.hist)
-                trace_deny = np.asarray(self._trace.deny_count)
-                trace_emul = np.asarray(self._trace.emul_count)
-                trace_kill = np.asarray(self._trace.kill_count)
+        with self._phase("harvest/readback"):
+            halted = np.asarray(self._states.halted)
+            icount = np.asarray(self._states.icount)
+            # occupancy ledger: lane-steps actually executed this
+            # generation vs the lane-steps the dispatch paid for (bucket
+            # width x chunks run)
+            delta = icount - self._prev_icount
+            chunks_run = int(-(-int(delta.max()) // self.chunk)) \
+                if delta.max() else 0
+            self.dispatched_steps += self._W * chunks_run * self.chunk
+            self.executed_steps += int(delta.sum())
+            self._prev_icount = icount.copy()
+            patched = F.finish_halt_codes(halted, icount,
+                                          self._fuel[self._order])
+            done = patched != M.RUNNING
+            if done.any():  # one transfer per field, only when publishing
+                enosys = np.asarray(self._states.enosys_count)
+                emul_served = np.asarray(self._states.emul_served)
+                if self._trace is not None:
+                    if self._stream is None:
+                        # classic mode decodes rings from the carry;
+                        # streamed lanes publish from the TraceStream, so
+                        # the (large) double-buffer transfer is skipped
+                        trace_buf = np.asarray(self._trace.buf)
+                    trace_cnt = np.asarray(self._trace.count)
+                    trace_hist = np.asarray(self._trace.hist)
+                    trace_deny = np.asarray(self._trace.deny_count)
+                    trace_emul = np.asarray(self._trace.emul_count)
+                    trace_kill = np.asarray(self._trace.kill_count)
 
         # batch C3 diagnosis over every faulted, recyclable lane at once
         # (indexed by physical lane, like the device arrays)
-        c3_pps: List[Optional[PreparedProcess]] = [None] * self._W
-        for i in range(self._W):
-            req = self._slots[self._order[i]]
-            if (req is not None and done[i]
-                    and halted[i] == M.HALT_SEGV
-                    and req.builder is not None and req.cfg.enable_c3):
-                c3_pps[i] = req.pp
-        events = (diagnose_c3_fleet(c3_pps, self._states, halted=halted)
-                  if any(p is not None for p in c3_pps)
-                  else [None] * self._W)
+        with self._phase("harvest/c3"):
+            c3_pps: List[Optional[PreparedProcess]] = [None] * self._W
+            for i in range(self._W):
+                req = self._slots[self._order[i]]
+                if (req is not None and done[i]
+                        and halted[i] == M.HALT_SEGV
+                        and req.builder is not None and req.cfg.enable_c3):
+                    c3_pps[i] = req.pp
+            events = (diagnose_c3_fleet(c3_pps, self._states, halted=halted)
+                      if any(p is not None for p in c3_pps)
+                      else [None] * self._W)
 
         results: List[FleetResult] = []
         for i in range(self._W):
@@ -965,107 +979,119 @@ class FleetServer:
                 continue
             ev = events[i]
             if ev is not None:
-                # append to the "config file" (Figure 4) — even on the final
-                # attempt, exactly as run_with_c3 does
-                req.cfg.pin(lib=ev.lib, offset=ev.offset,
-                            syscall_nr=ev.syscall_nr)
-                req.events.append(ev)
-            if ev is not None and req.attempts < req.cfg.serve_max_restarts:
-                # trap -> config -> re-execute, without leaving the fleet.
-                # Admission order guards against a transiently full table:
-                # a solely-owned row is released first (its slot then serves
-                # the re-prepared image); a shared row needs a spare slot,
-                # and if none exists the fault is published instead of
-                # corrupting the harvest.
-                new_pp = prepare(req.builder(), req.mechanism,
-                                 virtualize=req.virtualize, cfg=req.cfg)
-                if self.table.refs(req.row) == 1:
-                    self.table.release(req.row)
-                    new_row = self.table.admit(new_pp)
-                else:
-                    try:
-                        new_row = self.table.admit(new_pp)
-                    except ImageTableFull:
-                        new_row = None
-                    if new_row is not None:
-                        self.table.release(req.row)
-                if new_row is not None:
-                    req.pp, req.row = new_pp, new_row
-                    req.attempts += 1
-                    self.discarded_steps += int(icount[i])
-                    req.parked_gen = self.generation
-                    req.parked_s = obs_now()
-                    self._readmit.append(req)
-                    self._readmit_rids.add(req.rid)
-                    if self._stream is not None:
-                        # the published trace must hold only the final
-                        # attempt's records; the epoch bump keeps sink
-                        # dedup correct across attempts
-                        self._stream.reset(req.rid)
-                    # a C3 recycle restarts the attempt from scratch and
-                    # its ring counters reset with it: roll any usage the
-                    # discarded attempt already charged (at a preemption /
-                    # budget checkpoint) back OUT of the ledger, or the
-                    # replay would double-bill the same syscalls
-                    self._charge(req, 0, 0, 0, 0)
-                    self.c3_readmissions += 1
+                with self._phase("harvest/c3"):
+                    recycled = self._recycle_c3(req, ev, int(icount[i]))
+                if recycled:
                     continue
-            lane = F.unstack_state(self._states, i)
-            if patched[i] != halted[i]:  # ran out of fuel mid-generation
-                lane = lane._replace(halted=jnp.int64(int(patched[i])))
-            if self._trace is None:
-                recs, dropped = [], 0
-                hist = {}
-            else:
-                if self._stream is not None:
-                    # streamed dispatch ends every generation with a flip,
-                    # so the lane's full record stream already sits in the
-                    # sink — publish is a pop, not a device decode
-                    recs, dropped = self._stream.pop(req.rid)
+            with self._phase("harvest/unstack"):
+                lane = F.unstack_state(self._states, i)
+                if patched[i] != halted[i]:  # out of fuel mid-generation
+                    lane = lane._replace(halted=jnp.int64(int(patched[i])))
+            with self._phase("harvest/publish"):
+                if self._trace is None:
+                    recs, dropped = [], 0
+                    hist = {}
                 else:
-                    recs, dropped = trace_recorder.harvest_lane(
-                        trace_buf[i], trace_cnt[i])
-                hist = trace_recorder.lane_histogram(trace_hist[i])
-                self._hist_total += trace_hist[i]
-            results.append(FleetResult(
-                rid=req.rid, state=lane, events=req.events,
-                attempts=req.attempts, submitted_gen=req.submitted_gen,
-                admitted_gen=req.admitted_gen, completed_gen=self.generation,
-                admission_wait_gens=req.admitted_gen - req.submitted_gen,
-                admission_wait_s=req.admitted_s - req.submitted_s,
-                trace=recs, trace_dropped=dropped, histogram=hist,
-                tenant=req.tenant, preemptions=req.preemptions))
-            self.harvested_steps += int(icount[i])
-            self.enosys_total += int(enosys[i])
-            self.emul_served_total += int(emul_served[i])
-            self.trace_records += len(recs)
-            self.trace_dropped += dropped
-            self.completed += 1
-            if self._obs is not None:
-                self._obs.spans.event(str(req.rid), "complete",
-                                      req.tenant or "default")
-            if self._trace is not None:
-                self._charge(req, int(trace_cnt[i]), int(trace_deny[i]),
-                             int(trace_emul[i]), int(trace_kill[i]),
-                             enosys=int(enosys[i]))
-            else:
-                self._charge(req, req.charged_svc, req.charged_deny,
-                             req.charged_emul, req.charged_kill,
-                             enosys=int(enosys[i]))
-            t = self._tstat(req.tenant)
-            t["completed"] += 1
-            if self.sched is not None:
-                if patched[i] == M.HALT_KILL:
+                    if self._stream is not None:
+                        # streamed dispatch ends every generation with a
+                        # flip, so the lane's full record stream already
+                        # sits in the sink — publish is a pop, not a
+                        # device decode
+                        recs, dropped = self._stream.pop(req.rid)
+                    else:
+                        recs, dropped = trace_recorder.harvest_lane(
+                            trace_buf[i], trace_cnt[i])
+                    hist = trace_recorder.lane_histogram(trace_hist[i])
+                    self._hist_total += trace_hist[i]
+                results.append(FleetResult(
+                    rid=req.rid, state=lane, events=req.events,
+                    attempts=req.attempts, submitted_gen=req.submitted_gen,
+                    admitted_gen=req.admitted_gen,
+                    completed_gen=self.generation,
+                    admission_wait_gens=req.admitted_gen - req.submitted_gen,
+                    admission_wait_s=req.admitted_s - req.submitted_s,
+                    trace=recs, trace_dropped=dropped, histogram=hist,
+                    tenant=req.tenant, preemptions=req.preemptions))
+                self.harvested_steps += int(icount[i])
+                self.enosys_total += int(enosys[i])
+                self.emul_served_total += int(emul_served[i])
+                self.trace_records += len(recs)
+                self.trace_dropped += dropped
+                self.completed += 1
+                if self._obs is not None:
+                    self._obs.spans.event(str(req.rid), "complete",
+                                          req.tenant or "default")
+                if self._trace is not None:
+                    self._charge(req, int(trace_cnt[i]), int(trace_deny[i]),
+                                 int(trace_emul[i]), int(trace_kill[i]),
+                                 enosys=int(enosys[i]))
+                else:
+                    self._charge(req, req.charged_svc, req.charged_deny,
+                                 req.charged_emul, req.charged_kill,
+                                 enosys=int(enosys[i]))
+                t = self._tstat(req.tenant)
+                t["completed"] += 1
+                if self.sched is not None:
+                    if patched[i] == M.HALT_KILL:
+                        t["killed"] += 1
+                        self.sched.quarantine.punish(
+                            req.tenant, self.generation, reason="halt_kill")
+                    elif patched[i] == M.HALT_EXIT:
+                        self.sched.quarantine.clear(req.tenant)
+                elif patched[i] == M.HALT_KILL:
                     t["killed"] += 1
-                    self.sched.quarantine.punish(req.tenant, self.generation,
-                                                 reason="halt_kill")
-                elif patched[i] == M.HALT_EXIT:
-                    self.sched.quarantine.clear(req.tenant)
-            elif patched[i] == M.HALT_KILL:
-                t["killed"] += 1
-            self.table.release(req.row)
-            self._slots[self._order[i]] = None
+                self.table.release(req.row)
+                self._slots[self._order[i]] = None
         return results
+
+    def _recycle_c3(self, req: FleetRequest, ev: C3Event,
+                    icount: int) -> bool:
+        """Record a lane's C3 event and, while restarts remain, re-prepare
+        its image and queue it for re-admission; True if it was queued."""
+        # append to the "config file" (Figure 4) — even on the final
+        # attempt, exactly as run_with_c3 does
+        req.cfg.pin(lib=ev.lib, offset=ev.offset, syscall_nr=ev.syscall_nr)
+        req.events.append(ev)
+        if req.attempts >= req.cfg.serve_max_restarts:
+            return False
+        # trap -> config -> re-execute, without leaving the fleet.
+        # Admission order guards against a transiently full table: a
+        # solely-owned row is released first (its slot then serves the
+        # re-prepared image); a shared row needs a spare slot, and if none
+        # exists the fault is published instead of corrupting the harvest.
+        new_pp = prepare(req.builder(), req.mechanism,
+                         virtualize=req.virtualize, cfg=req.cfg)
+        if self.table.refs(req.row) == 1:
+            self.table.release(req.row)
+            new_row = self.table.admit(new_pp)
+        else:
+            try:
+                new_row = self.table.admit(new_pp)
+            except ImageTableFull:
+                new_row = None
+            if new_row is not None:
+                self.table.release(req.row)
+        if new_row is None:
+            return False
+        req.pp, req.row = new_pp, new_row
+        req.attempts += 1
+        self.discarded_steps += icount
+        req.parked_gen = self.generation
+        req.parked_s = obs_now()
+        self._readmit.append(req)
+        self._readmit_rids.add(req.rid)
+        if self._stream is not None:
+            # the published trace must hold only the final attempt's
+            # records; the epoch bump keeps sink dedup correct across
+            # attempts
+            self._stream.reset(req.rid)
+        # a C3 recycle restarts the attempt from scratch and its ring
+        # counters reset with it: roll any usage the discarded attempt
+        # already charged (at a preemption / budget checkpoint) back OUT of
+        # the ledger, or the replay would double-bill the same syscalls
+        self._charge(req, 0, 0, 0, 0)
+        self.c3_readmissions += 1
+        return True
 
     def _phase(self, name: str):
         """Phase timer against this server's hub (a shared no-op when
@@ -1074,13 +1100,13 @@ class FleetServer:
 
     def _dispatch(self, ids: np.ndarray) -> None:
         if self._trace is None:
-            with self._phase("dispatch"):
+            with self._phase("dispatch"), self._phase("dispatch/enqueue"):
                 self._states = F.run_fleet_span(
                     self.table.images, self._states, ids,
                     steps=self.gen_steps, chunk=self.chunk,
                     engine=self.engine)
         elif self._stream is None:
-            with self._phase("dispatch"):
+            with self._phase("dispatch"), self._phase("dispatch/enqueue"):
                 self._states, self._trace = F.run_fleet_span(
                     self.table.images, self._states, ids,
                     steps=self.gen_steps, chunk=self.chunk, trace=self._trace,
@@ -1094,7 +1120,12 @@ class FleetServer:
         sub-span (worst case one record per step), so every record reaches
         the stream — zero drops at fixed ring capacity.  Each cold half's
         host conversion is deferred until after the NEXT sub-span's
-        dispatch, so the device->host copy overlaps device compute."""
+        dispatch, so the device->host copy overlaps device compute.
+
+        The flip reads the pre-flip counts back to the host, which waits
+        for the sub-span just enqueued: that wait is taken first, on its
+        own (``dispatch/device_wait``), so the enqueues' host time is not
+        mixed with time the chip spends running the sub-span."""
         interval = F.stream_interval(self.cfg.trace_cap, self.chunk)
         keys = [self._slots[self._order[p]].rid
                 if self._slots[self._order[p]] is not None else None
@@ -1103,7 +1134,7 @@ class FleetServer:
         pending = None
         while left > 0:
             steps = min(interval, left)
-            with self._phase("dispatch"):
+            with self._phase("dispatch"), self._phase("dispatch/enqueue"):
                 self._states, self._trace = F.run_fleet_span(
                     self.table.images, self._states, ids,
                     steps=steps, chunk=self.chunk, trace=self._trace,
@@ -1112,7 +1143,11 @@ class FleetServer:
                 with self._phase("stream_flush"):
                     self._stream.push_block(keys, *pending)
             with self._phase("dispatch"):
-                self._trace, cold, counts, bases = F.flip_trace(self._trace)
+                with self._phase("dispatch/device_wait"):
+                    jax.block_until_ready(self._trace.count)
+                with self._phase("dispatch/enqueue"):
+                    self._trace, cold, counts, bases = F.flip_trace(
+                        self._trace)
             pending = (cold, counts, bases)
             left -= steps
         with self._phase("stream_flush"):
@@ -1205,17 +1240,20 @@ class FleetServer:
         An observed server (``repro.obs``) times the whole generation and
         each stage of it through the phase profiler, refreshes the ledger
         gauges, and gives the snapshot sink a chance to write — all
-        host-side bookkeeping; published states stay bit-identical."""
-        if self._obs is None:
-            return self._step()
-        t0 = obs_now()
-        self._obs.gen_begin(t0)
-        try:
-            return self._step()
-        finally:
-            self._obs.maybe_snapshot()
-            self._refresh_gauges()
-            self._obs.gen_end(t0)
+        host-side bookkeeping; published states stay bit-identical.  While
+        a profiler trace is active the generation is a ``fleet.generation``
+        step annotation, numbered by generation, with the rung width."""
+        with step_annotation(self.generation, rung=self._W):
+            if self._obs is None:
+                return self._step()
+            t0 = obs_now()
+            self._obs.gen_begin(t0)
+            try:
+                return self._step()
+            finally:
+                self._obs.maybe_snapshot()
+                self._refresh_gauges()
+                self._obs.gen_end(t0)
 
     def _step(self) -> List[FleetResult]:
         if self.sched is not None:

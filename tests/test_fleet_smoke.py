@@ -111,3 +111,22 @@ def test_run_fleet_shard_path():
     pps = [prepare(programs.getpid_loop(2), Mechanism.NONE) for _ in range(2)]
     out = run_fleet_prepared(pps, fuel=50_000, shard=True)
     assert np.asarray(out.halted).tolist() == [HALT_EXIT, HALT_EXIT]
+
+
+def test_span_parts_carry_named_scopes():
+    """The traced span's parts are named scopes in its lowered text, so a
+    device trace can charge each operation to one of them."""
+    import re
+    n = 8
+    imgs = jax.eval_shape(lambda: fleet.FleetImages(
+        packed=jnp.zeros((4, L.CODE_WORDS), jnp.int64),
+        imm=jnp.zeros((4, L.CODE_WORDS), jnp.int64)))
+    ids = jax.ShapeDtypeStruct((n,), jnp.int32)
+    states = jax.eval_shape(lambda: fleet.make_halted_states(n))
+    trace = jax.eval_shape(lambda: fleet.make_empty_trace(n, 64))
+    text = fleet._jitted_span_traced(8, 16).lower(
+        imgs, ids, states, trace).as_text(debug_info=True)
+    locs = set(re.findall(r'loc\("([^"]*)"', text))
+    for scope in ("fetch", "regs", "mem", "alu", "syscall", "emul",
+                  "io_mover", "trace_ring"):
+        assert any(re.search(rf"(^|/){scope}/", s) for s in locs), scope

@@ -22,6 +22,7 @@ monotone (never below any value a ``metrics()`` caller could have
 read) and every span still completes.  Example counts scale via
 ASC_TEST_EXAMPLES.
 """
+import dataclasses
 import json
 import os
 
@@ -30,7 +31,8 @@ import pytest
 
 from repro.core import HookConfig, Mechanism, prepare, programs
 from repro.obs import (ObsHub, PHASES, MetricsRegistry, make_sink, now,
-                       phase as obs_phase)
+                       phase as obs_phase,
+                       step_annotation as obs_step_annotation)
 from repro.obs.metrics import (JsonlSink, MemorySink, PromFileSink,
                                _bucket_index, _bucket_upper)
 from repro.sched import PolicyScheduler, TenantBudget
@@ -274,6 +276,126 @@ def test_profiler_inflight_credit_in_exports():
         assert hub.profiler.counts.get("snapshot_write") is None
     assert hub.profiler.counts["snapshot_write"] == 1
     assert hub.profiler.export()["counts"]["snapshot_write"] == 1
+
+
+def _streamed_server(obs=True, pool=2):
+    cfg = HookConfig(obs_enabled=obs, trace_enabled=True, trace_cap=8)
+    return FleetServer(pool=pool, cfg=cfg, gen_steps=48, chunk=8, fuel=FUEL,
+                       stream=True)
+
+
+def _submit_streamed(srv):
+    for i in range(3):
+        srv.submit(_pp("getpid"), regs={19: 4 + i}, tenant="a")
+        srv.submit(_pp("storm"), regs={19: 6, 20: 2, 21: 8}, tenant="b")
+
+
+def test_child_phases_nest_under_their_parents():
+    srv = _streamed_server()
+    _submit_streamed(srv)
+    _drain(srv)
+    m = srv.metrics()
+    phases = m["phases"]
+    children = [n for n in phases if "/" in n]
+    for name in ("admission/initial_state", "admission/image_row",
+                 "admission/scatter", "dispatch/enqueue",
+                 "dispatch/device_wait", "harvest/readback", "harvest/c3",
+                 "harvest/unstack", "harvest/publish"):
+        assert name in children, name
+    for name in children:
+        assert name in PHASES
+        parent = name.split("/")[0]
+        assert set(phases[name]) == set(phases[parent])
+        assert phases[name]["count"] >= 1
+        assert phases[name]["total_s"] <= phases[parent]["total_s"]
+    for parent in ("admission", "dispatch", "harvest"):
+        kids = sum(phases[n]["total_s"] for n in children
+                   if n.startswith(parent + "/"))
+        assert kids <= phases[parent]["total_s"]
+    # children are never counted twice: coverage keeps its meaning
+    assert 0.75 <= m["phase_coverage"] <= 1.05, m["phase_coverage"]
+    top = sum(p["total_s"] for n, p in phases.items() if "/" not in n)
+    assert m["phase_coverage"] == pytest.approx(
+        top / m["generation"]["total_s"])
+
+
+def test_nested_inflight_exports_and_watermarks_stay_monotone():
+    hub = ObsHub()
+    with hub.phase("harvest"):
+        with hub.phase("harvest/unstack"):
+            d = hub.profiler.export()
+            wm = hub.watermark()
+            # both open timers are credited, the child and its parent
+            for name in ("harvest", "harvest/unstack"):
+                assert d["counts"][name] == 1
+                assert wm["profile"]["counts"][name] == 1
+                assert hub.profiler.counts.get(name) is None
+        mid = hub.profiler.export()
+        assert mid["counts"] == {"harvest": 1, "harvest/unstack": 1}
+        assert mid["totals"]["harvest/unstack"] >= \
+            d["totals"]["harvest/unstack"]
+        assert mid["totals"]["harvest"] >= d["totals"]["harvest"]
+    final = hub.profiler.export()
+    for name in ("harvest", "harvest/unstack"):
+        assert final["counts"][name] == 1
+        assert final["totals"][name] >= mid["totals"][name]
+    assert final["totals"]["harvest/unstack"] <= final["totals"]["harvest"]
+    # a server recovered from the export taken inside the child, floored
+    # at the watermark, never sits below what was read inside it
+    rec = ObsHub()
+    rec.restore({"profiler": d})
+    rec.apply_watermark(wm)
+    for name in ("harvest", "harvest/unstack"):
+        assert rec.profiler.counts[name] == 1
+        assert rec.profiler.totals[name] >= wm["profile"]["totals"][name]
+
+
+def test_timers_build_no_annotation_without_a_profiler_trace():
+    hub = ObsHub()
+    with hub.phase("dispatch") as t:
+        assert t._annot is None
+    with obs_step_annotation(3, rung=8) as s:
+        assert s is obs_phase(None, "harvest")    # the shared no-op
+
+
+def test_streamed_observed_run_is_bit_identical_to_unobserved():
+    def run(obs):
+        srv = _streamed_server(obs)
+        _submit_streamed(srv)
+        return sorted((_state_key(r),
+                       tuple(dataclasses.astuple(t) for t in r.trace))
+                      for r in _drain(srv))
+
+    assert run(False) == run(True)
+
+
+def test_profiler_trace_holds_the_fleet_spans(tmp_path):
+    """Phase and child spans, and the generation step annotation, are
+    written into a ``jax.profiler`` trace beside the device's events."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    srv = _streamed_server()
+    _submit_streamed(srv)
+    _drain(srv)                                  # compiles outside the trace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0                 # as the chip benchmark
+    opts.host_tracer_level = 1                   # traces its served cell
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        _submit_streamed(srv)
+        _drain(srv)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    for name in ("fleet.admission", "fleet.admission/initial_state",
+                 "fleet.dispatch/device_wait", "fleet.harvest/unstack",
+                 "fleet.generation"):
+        assert name in names, name
 
 
 # -- lifecycle spans + resume-wait split --------------------------------------
