@@ -177,6 +177,23 @@ def unstack_state(states: MachineState, lane: int) -> MachineState:
     return jax.tree_util.tree_map(lambda x: x[lane], states)
 
 
+def flat_planes(s: MachineState) -> MachineState:
+    """The per-lane word planes (``mem``, ``k_ino_data``) as one flat
+    ``[B * W]`` plane each, lane ``b``'s words at ``b * W``: the layout
+    :func:`exec_lanes` addresses.  Drivers flatten once before their loop
+    and restore with :func:`lane_planes` once after it."""
+    return s._replace(mem=s.mem.reshape(-1),
+                      k_ino_data=s.k_ino_data.reshape(-1))
+
+
+def lane_planes(s: MachineState) -> MachineState:
+    """Inverse of :func:`flat_planes`: back to ``[B, W]`` per plane."""
+    B = s.pc.shape[0]
+    return s._replace(mem=s.mem.reshape(B, L.MEM_WORDS),
+                      k_ino_data=s.k_ino_data.reshape(
+                          B, L.MAX_INODES * L.FILE_WORDS))
+
+
 # ---------------------------------------------------------------------------
 # the batched step
 # ---------------------------------------------------------------------------
@@ -229,9 +246,12 @@ def exec_lanes(fields, s: MachineState, tr: Optional[TraceState],
 
     ``fields`` is :func:`_fetch`'s tuple (any decode source works: the
     packed fleet tables, or the scalar SoA tables in
-    :func:`repro.core.machine.step`).  ``act`` overrides the live-lane
-    mask — the scalar engine forces all-true to reproduce the legacy
-    unconditional step; fleet drivers leave the default halted/fuel gate.
+    :func:`repro.core.machine.step`).  ``s`` holds its word planes flat
+    (:func:`flat_planes`), and so does the returned state: every caller
+    flattens once around its loop, so no step reshapes a plane.  ``act``
+    overrides the live-lane mask — the scalar engine forces all-true to
+    reproduce the legacy unconditional step; fleet drivers leave the
+    default halted/fuel gate.
 
     ``tr is None`` keeps the graph unchanged from the untraced engine;
     with a trace carry the syscall ring + policy tables ride along and
@@ -254,7 +274,7 @@ def exec_lanes(fields, s: MachineState, tr: Optional[TraceState],
     op, rd, rn, rm, sh, cond, sf, imm = fields
     B = s.pc.shape[0]
     lanes = jnp.arange(B)
-    regs0, sp0, pc0, nzcv0, mem0 = s.regs, s.sp, s.pc, s.nzcv, s.mem
+    regs0, sp0, pc0, nzcv0, mem_flat = s.regs, s.sp, s.pc, s.nzcv, s.mem
 
     if act is None:
         act = (s.halted == RUNNING) & (s.icount < s.fuel)
@@ -311,9 +331,11 @@ def exec_lanes(fields, s: MachineState, tr: Optional[TraceState],
         addr2 = addr_a + 8
         ok2 = _mem_ok_v(addr2)
         g1, g2 = _widx_v(eff1), _widx_v(addr2)
-        # Flat 1-D addressing: [B, MEM_WORDS] -> [B*MEM_WORDS] is a bitcast,
-        # and rank-1 gathers/scatters take XLA's fast in-place path on CPU.
-        mem_flat = mem0.reshape(-1)
+        # Flat 1-D addressing: rank-1 gathers/scatters take XLA's fast
+        # in-place path on CPU.  The plane arrives flat: on CPU a
+        # [B, MEM_WORDS] <-> [B*MEM_WORDS] view is a bitcast, but on a TPU
+        # [B, W] is tiled T(8,128) and [B*W] T(1024), so each view is a full
+        # relayout of the plane; callers flatten once per loop instead.
         lane_base = (lanes * L.MEM_WORDS).astype(I64)
         # The word reads live behind a (vacuously true while any lane runs)
         # batch-uniform cond.  Expressed as bare gathers, XLA's CPU pipeline
@@ -566,6 +588,20 @@ def exec_lanes(fields, s: MachineState, tr: Optional[TraceState],
         signo = tbl.SIGNO[op]
         frame_out = jnp.concatenate(
             [regs0, sp0[:, None], pc0[:, None], nzcv0[:, None]], axis=1)
+        frame_start = lane_base + _SIGFRAME_IDX
+
+        # Sigreturn frame read, from the pre-store plane like the word
+        # reads: a sigreturn lane performs no store/push/I-O in the same
+        # step, so its row is the scalar engine's pre-handler read.  Rare op
+        # => batch-uniform cond; every consumer is masked by sys_sigret.  A
+        # read of the final plane, after every writer, instead makes a TPU's
+        # copy insertion copy the whole plane before the store scatter
+        # every step.
+        frame_in = lax.cond(
+            jnp.any(sys_sigret),
+            lambda: mem_flat[frame_start[:, None]
+                             + jnp.arange(SIGFRAME_WORDS, dtype=I64)],
+            lambda: jnp.zeros((B, SIGFRAME_WORDS), I64))
 
     # -- memory writes -------------------------------------------------------
     # One merged scatter for both store slots.  Disabled / faulting writes
@@ -587,26 +623,35 @@ def exec_lanes(fields, s: MachineState, tr: Optional[TraceState],
         # indices are genuinely unique: live pair slots differ by construction,
         # parked slots each get their own out-of-bounds id (dropped)
         mem = mem_flat.at[st_idx].set(st_val, mode="drop",
-                                      unique_indices=True).reshape(
-                                          B, L.MEM_WORDS)
+                                      unique_indices=True)
 
-        # Sigframe push is rare (only brk/illegal on a lane with a handler):
-        # keep the 34-word window write behind a batch-uniform cond.
-        def push_frames(mm):
-            cur = mm[:, _SIGFRAME_IDX:_SIGFRAME_IDX + SIGFRAME_WORDS]
-            return mm.at[:, _SIGFRAME_IDX:_SIGFRAME_IDX + SIGFRAME_WORDS].set(
-                jnp.where(can_sig[:, None], frame_out, cur))
+        # Sigframe push: rare (only brk/illegal on a lane with a handler),
+        # but lanes running one program trap on the same step.  A TPU
+        # scatter costs the same per index whether it lands or is parked
+        # (B * 34 indices: 16.6 ms on a v5e at 4,096 lanes, against 5.7 ms
+        # for this loop with 1,024 lanes pushing), so the push walks only
+        # the pushing lanes, one 34-word dynamic_update_slice each, in a
+        # bare loop like the io mover's: zero trips without a push.
+        n_push = jnp.sum(can_sig)
+        push_order = lax.cond(
+            n_push > 0,
+            lambda: jnp.argsort(~can_sig, stable=True).astype(lanes.dtype),
+            lambda: lanes)
 
-        mem = lax.cond(jnp.any(can_sig), push_frames, lambda mm: mm, mem)
+        def push_frame(k, mm):
+            b = push_order[k]
+            return lax.dynamic_update_slice(mm, frame_out[b],
+                                            (frame_start[b],))
+
+        mem = lax.fori_loop(jnp.int64(0), n_push, push_frame, mem)
 
     # fstat statbuf / pipe2 fd-pair result words: <= 6 words fleet-wide,
-    # parked out-of-bounds + dropped when masked, behind the same
-    # batch-uniform cond discipline as the sigframe push.
+    # parked out-of-bounds + dropped when masked, behind a batch-uniform
+    # cond.
     with jax.named_scope("emul"):
         def emul_result_words(mm):
-            return mm.reshape(-1).at[eff.scat_idx].set(
-                eff.scat_val, mode="drop",
-                unique_indices=True).reshape(B, L.MEM_WORDS)
+            return mm.at[eff.scat_idx].set(eff.scat_val, mode="drop",
+                                           unique_indices=True)
 
         mem = lax.cond(jnp.any(eff.scat_do), emul_result_words,
                        lambda mm: mm, mem)
@@ -658,10 +703,8 @@ def exec_lanes(fields, s: MachineState, tr: Optional[TraceState],
             rem = rem.at[b].set(False)
             return mf, sums, rem
 
-        mem_io, io_sum, _ = lax.while_loop(
-            lambda c: jnp.any(c[2]), io_lane_body,
-            (mem.reshape(-1), zero, io_do))
-        mem = mem_io.reshape(B, L.MEM_WORDS)
+        mem, io_sum, _ = lax.while_loop(
+            lambda c: jnp.any(c[2]), io_lane_body, (mem, zero, io_do))
 
     # Guest-kernel bulk data (file/pipe/proc reads+writes, getrandom
     # fills): the same bare-while-loop discipline over the (memory,
@@ -671,23 +714,8 @@ def exec_lanes(fields, s: MachineState, tr: Optional[TraceState],
             jnp.any(eff.src_is_proc),
             lambda: emul_engine.proc_rows(s).reshape(-1),
             lambda: jnp.zeros((B * L.PROC_WORDS,), I64))
-        mem_fio, ino_flat = emul_engine.run_data_loop(
-            mem.reshape(-1), eff.kern.ino_data.reshape(-1), proc_flat, eff)
-        mem = mem_fio.reshape(B, L.MEM_WORDS)
-        k_ino_data = ino_flat.reshape(B, L.MAX_INODES * L.FILE_WORDS)
-
-    # Sigreturn frame read — from the FINAL memory, after all writes.  A
-    # sigreturn lane performs no store/push/I-O in the same step, so its row
-    # is untouched and this equals the scalar engine's pre-handler read; and
-    # because no write follows, memory's liveness is not extended across a
-    # writer, which would force XLA to copy the whole [B, MEM_WORDS] buffer
-    # every step (measured ~15x slowdown).  Rare op => batch-uniform cond;
-    # the zeros fallback is safe: every consumer is masked by sys_sigret.
-    with jax.named_scope("mem"):
-        frame_in = lax.cond(
-            jnp.any(sys_sigret),
-            lambda: mem[:, _SIGFRAME_IDX:_SIGFRAME_IDX + SIGFRAME_WORDS],
-            lambda: jnp.zeros((B, SIGFRAME_WORDS), I64))
+        mem, k_ino_data = emul_engine.run_data_loop(
+            mem, eff.kern.ino_data, proc_flat, eff)
 
     # -- register writes (slot order mirrors the scalar handler order) ------
     with jax.named_scope("regs"):
@@ -858,7 +886,8 @@ def _step_core(img: FleetImages, ids: jnp.ndarray, s: MachineState,
                tr: Optional[TraceState],
                tbl: Optional["opspec.SpecTables"] = None):
     """One masked step for every lane: fetch/decode, then the shared
-    spec-generated executor body (``tbl`` as in :func:`exec_lanes`)."""
+    spec-generated executor body (``tbl`` as in :func:`exec_lanes`).
+    Takes and returns the word planes flat (:func:`flat_planes`)."""
     return exec_lanes(_fetch(img, ids, s.pc), s, tr, tbl=tbl)
 
 
@@ -870,7 +899,7 @@ def fleet_step(img: FleetImages, ids: jnp.ndarray,
     Bit-identical per lane to :func:`machine.step` applied to live lanes and
     the identity on halted/out-of-fuel lanes.
     """
-    return _step_core(img, ids, s, None)[0]
+    return lane_planes(_step_core(img, ids, flat_planes(s), None)[0])
 
 
 def fleet_step_traced(img: FleetImages, ids: jnp.ndarray, s: MachineState,
@@ -879,7 +908,8 @@ def fleet_step_traced(img: FleetImages, ids: jnp.ndarray, s: MachineState,
     record per executed svc and applies the per-lane policy tables.  Under
     the default all-ALLOW policy the returned machine state is bit-identical
     to the untraced step's (enforced by the repro.trace parity suite)."""
-    return _step_core(img, ids, s, tr)
+    s, tr = _step_core(img, ids, flat_planes(s), tr)
+    return lane_planes(s), tr
 
 
 # ---------------------------------------------------------------------------
@@ -896,17 +926,21 @@ def _patch_fuel(s: MachineState) -> MachineState:
         jnp.int64(HALT_FUEL), s.halted))
 
 
+# Every driver carries the word planes flat through its whole loop
+# (flat_planes before, lane_planes after): one reshape each way per
+# dispatch, none per step.
+
 def _run_fleet(img: FleetImages, ids: jnp.ndarray, s: MachineState,
                chunk: int) -> MachineState:
     def scan_body(carry, _):
-        return fleet_step(img, ids, carry), None
+        return _step_core(img, ids, carry, None)[0], None
 
     def body(ss):
         ss, _ = lax.scan(scan_body, ss, None, length=chunk)
         return ss
 
-    s = lax.while_loop(lambda ss: jnp.any(_alive(ss)), body, s)
-    return _patch_fuel(s)
+    s = lax.while_loop(lambda ss: jnp.any(_alive(ss)), body, flat_planes(s))
+    return _patch_fuel(lane_planes(s))
 
 
 def _run_fleet_traced(img: FleetImages, ids: jnp.ndarray, s: MachineState,
@@ -919,8 +953,9 @@ def _run_fleet_traced(img: FleetImages, ids: jnp.ndarray, s: MachineState,
         c, _ = lax.scan(scan_body, c, None, length=chunk)
         return c
 
-    s, tr = lax.while_loop(lambda c: jnp.any(_alive(c[0])), body, (s, tr))
-    return _patch_fuel(s), tr
+    s, tr = lax.while_loop(lambda c: jnp.any(_alive(c[0])), body,
+                           (flat_planes(s), tr))
+    return _patch_fuel(lane_planes(s)), tr
 
 
 @functools.lru_cache(maxsize=None)
@@ -1004,7 +1039,7 @@ def _run_fleet_span(img: FleetImages, ids: jnp.ndarray, s: MachineState,
     a fleet can keep stepping across generations and the server patches the
     halt code only when it harvests the lane."""
     def scan_body(carry, _):
-        return fleet_step(img, ids, carry), None
+        return _step_core(img, ids, carry, None)[0], None
 
     def body(c):
         ss, k = c
@@ -1015,8 +1050,8 @@ def _run_fleet_span(img: FleetImages, ids: jnp.ndarray, s: MachineState,
         ss, k = c
         return jnp.any(_alive(ss)) & (k < span)
 
-    s, _ = lax.while_loop(cond, body, (s, jnp.int32(0)))
-    return s
+    s, _ = lax.while_loop(cond, body, (flat_planes(s), jnp.int32(0)))
+    return lane_planes(s)
 
 
 def _run_fleet_span_traced(img: FleetImages, ids: jnp.ndarray,
@@ -1035,8 +1070,9 @@ def _run_fleet_span_traced(img: FleetImages, ids: jnp.ndarray,
         (ss, _), k = c
         return jnp.any(_alive(ss)) & (k < span)
 
-    (s, tr), _ = lax.while_loop(cond, body, ((s, tr), jnp.int32(0)))
-    return s, tr
+    (s, tr), _ = lax.while_loop(cond, body,
+                                ((flat_planes(s), tr), jnp.int32(0)))
+    return lane_planes(s), tr
 
 
 @functools.lru_cache(maxsize=None)

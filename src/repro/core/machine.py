@@ -182,9 +182,9 @@ def step(img: DecodedImage, s: MachineState) -> MachineState:
     fields = tuple(_lift(a) for a in
                    (op, img.rd[idx], img.rn[idx], img.rm[idx], img.sh[idx],
                     img.cond[idx], img.sf[idx], img.imm[idx]))
-    sb = jax.tree_util.tree_map(_lift, s)
+    sb = F.flat_planes(jax.tree_util.tree_map(_lift, s))
     out, _ = F.exec_lanes(fields, sb, None, act=jnp.ones((1,), bool))
-    return jax.tree_util.tree_map(lambda x: x[0], out)
+    return jax.tree_util.tree_map(lambda x: x[0], F.lane_planes(out))
 
 
 def _run(img: DecodedImage, s: MachineState) -> MachineState:

@@ -79,7 +79,9 @@ def _make_kernel(chunk: int, traced: bool):
         out_refs = refs[3 + _N_TBL + n_carry:]
         img = F.FleetImages(packed=packed_ref[...], imm=imm_ref[...])
         ids = ids_ref[...]
-        s = MachineState(*(r[...] for r in in_refs[:_N_STATE]))
+        # the word planes flat for the whole chunk, as the XLA drivers
+        # carry them (repro.core.fleet.flat_planes)
+        s = F.flat_planes(MachineState(*(r[...] for r in in_refs[:_N_STATE])))
         if traced:
             tr = F.TraceState(*(r[...] for r in in_refs[_N_STATE:]))
 
@@ -87,14 +89,14 @@ def _make_kernel(chunk: int, traced: bool):
                 return F._step_core(img, ids, c[0], c[1], tbl=tbl)
 
             s, tr = lax.fori_loop(0, chunk, body, (s, tr))
-            outs = tuple(s) + tuple(tr)
+            outs = tuple(F.lane_planes(s)) + tuple(tr)
         else:
 
             def body(_, ss):
                 return F._step_core(img, ids, ss, None, tbl=tbl)[0]
 
             s = lax.fori_loop(0, chunk, body, s)
-            outs = tuple(s)
+            outs = tuple(F.lane_planes(s))
         for ref, val in zip(out_refs, outs):
             ref[...] = val
 

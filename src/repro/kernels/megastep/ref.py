@@ -23,16 +23,18 @@ from repro.core.machine import MachineState
 
 def megastep_chunk_ref(imgs: F.FleetImages, ids, s: MachineState,
                        tr: Optional[F.TraceState] = None, *, chunk: int):
-    """``chunk`` masked steps as the XLA engine runs them."""
+    """``chunk`` masked steps as the XLA engine runs them (word planes
+    flat across the chunk)."""
+    s = F.flat_planes(s)
     if tr is None:
         def body(ss, _):
             return F._step_core(imgs, ids, ss, None)[0], None
 
         s, _ = lax.scan(body, s, None, length=chunk)
-        return s
+        return F.lane_planes(s)
 
     def body_t(c, _):
         return F._step_core(imgs, ids, c[0], c[1]), None
 
     (s, tr), _ = lax.scan(body_t, (s, tr), None, length=chunk)
-    return s, tr
+    return F.lane_planes(s), tr

@@ -6,6 +6,11 @@ import pytest
 
 from repro.core import (Mechanism, prepare, programs, run_fleet_prepared,
                         run_prepared, unstack_state)
+from repro.core import isa
+from repro.core import layout as L
+from repro.core.image import APP_BASE
+from repro.core.isa import Asm
+from repro.emul.state import STAT_WORDS
 
 FUEL = 300_000
 
@@ -113,3 +118,144 @@ def test_image_dedup_shares_tables():
     assert imgs.packed.shape[0] == 2  # pp1/pp2 share, pp3 differs
     assert list(ids) == [0, 0, 1]
     assert states.pc.shape[0] == 3
+
+
+# -- lane edges of the flat word plane ----------------------------------------
+#
+# The fleet addresses every lane's memory as one flat [B * MEM_WORDS] plane
+# (lane b's words start at b * MEM_WORDS).  Each case drives one writer of
+# that plane at a lane's first and last words, with per-lane values (x19),
+# so an address that is one row off lands in a neighbour lane and shows as
+# a field mismatch against the scalar engine.
+
+EDGE_LANES = 3
+_LAST = L.MEM_LIMIT - 8
+
+
+def _imm(a, rd, value):
+    a.emit(*isa.mov_imm48(rd, value))
+
+
+def _edge_stores():
+    """str/strb/stp at the first and last words; the final pair's second
+    word clips at MEM_LIMIT (its first word lands, the lane faults)."""
+    a = Asm(APP_BASE)
+    a.label("main")
+    a.emit(isa.addi(20, 19, 1))
+    _imm(a, 21, L.DATA_BASE)
+    a.emit(isa.str_imm(19, 21))
+    _imm(a, 22, L.MEM_LIMIT - 16)
+    a.emit(isa.stp(19, 20, 22))
+    a.emit(isa.ldp(24, 25, 22))
+    _imm(a, 23, L.MEM_LIMIT - 1)
+    a.emit(isa.strb(20, 23))
+    _imm(a, 23, _LAST)
+    a.emit(isa.stp(20, 19, 23))      # second word past MEM_LIMIT
+    programs._exit0(a)
+    return a
+
+
+def _edge_results():
+    """A pipe2 fd pair into the first two words, fstat of its read end into
+    the last STAT_WORDS words, then x19 pipe2 pairs into the last two
+    words, so the final fds differ per lane."""
+    a = Asm(APP_BASE)
+    a.label("main")
+    _imm(a, 0, L.DATA_BASE)
+    a.emit(isa.movz(1, 0))
+    programs._raw(a, L.SYS_PIPE2)
+    _imm(a, 21, L.DATA_BASE)
+    a.emit(isa.ldr_imm(0, 21))       # the read end just written
+    _imm(a, 1, L.MEM_LIMIT - STAT_WORDS * 8)
+    programs._raw(a, L.SYS_FSTAT)
+    a.label("loop")
+    _imm(a, 0, L.MEM_LIMIT - 16)
+    a.emit(isa.movz(1, 0))
+    programs._raw(a, L.SYS_PIPE2)
+    a.emit(isa.subsi(19, 19, 1))
+    a.b_to("loop", cond="ne")
+    programs._exit0(a)
+    return a
+
+
+def _edge_stream(nbytes=4120):
+    """Stream reads whose io-mover windows end at the row's end (the second
+    512-word window overhangs it) and start at its first word, then a sink
+    write that sums the last words; x19 short reads first shift the fill."""
+    a = Asm(APP_BASE)
+    a.label("main")
+    _imm(a, 21, L.MEM_LIMIT - nbytes)
+    a.label("loop")
+    a.emit(isa.movz(0, 3), isa.mov_r(1, 21), isa.movz(2, 8))
+    a.bl_to("libc.so:read")
+    a.emit(isa.subsi(19, 19, 1))
+    a.b_to("loop", cond="ne")
+    a.emit(isa.movz(0, 3), isa.mov_r(1, 21))
+    _imm(a, 2, nbytes)
+    a.bl_to("libc.so:read")
+    a.emit(isa.movz(0, 3))
+    _imm(a, 1, L.DATA_BASE)
+    _imm(a, 2, nbytes)
+    a.bl_to("libc.so:read")
+    a.emit(isa.movz(0, 1), isa.mov_r(1, 21))
+    _imm(a, 2, nbytes)
+    a.bl_to("libc.so:write")
+    programs._exit0(a)
+    return a
+
+
+def _edge_data():
+    """Guest-kernel data loop: a file written from the lane's first words,
+    read back into its last FILE_BYTES, then x19 getrandom fills of the
+    last 8 words."""
+    a = Asm(APP_BASE)
+    a.label("main")
+    _imm(a, 21, L.DATA_BASE)
+    a.emit(isa.str_imm(19, 21))
+    _imm(a, 24, L.HEAP_BASE + 2048)
+    programs._store_path(a, 24, 25, b"edge.dat")
+    a.emit(isa.movz(0, 0), isa.mov_r(1, 24))
+    _imm(a, 2, L.O_CREAT)
+    programs._raw(a, L.SYS_OPENAT)
+    a.emit(isa.mov_r(23, 0))
+    a.emit(isa.mov_r(1, 21))
+    _imm(a, 2, L.FILE_BYTES)
+    a.bl_to("libc.so:write")
+    a.emit(isa.mov_r(0, 23), isa.movz(1, 0), isa.movz(2, L.SEEK_SET))
+    programs._raw(a, L.SYS_LSEEK)
+    a.emit(isa.mov_r(0, 23))
+    _imm(a, 1, L.MEM_LIMIT - L.FILE_BYTES)
+    _imm(a, 2, L.FILE_BYTES)
+    a.bl_to("libc.so:read")
+    a.label("loop")
+    _imm(a, 0, L.MEM_LIMIT - 64)
+    a.emit(isa.movz(1, 64), isa.movz(2, 0))
+    programs._raw(a, L.SYS_GETRANDOM)
+    a.emit(isa.subsi(19, 19, 1))
+    a.b_to("loop", cond="ne")
+    programs._exit0(a)
+    return a
+
+
+EDGE_CASES = {
+    "store_pair_clip": (_edge_stores, Mechanism.NONE),
+    "sigframe_push_and_sigreturn": (programs.getpid_loop_param,
+                                    Mechanism.SIGNAL),
+    "fstat_pipe2_result_words": (_edge_results, Mechanism.NONE),
+    "io_mover_row_end": (_edge_stream, Mechanism.NONE),
+    "data_loop_row_end": (_edge_data, Mechanism.NONE),
+}
+
+
+@pytest.mark.parametrize("case", sorted(EDGE_CASES))
+def test_flat_plane_lane_edges_match_scalar(case):
+    """Three lanes writing at their rows' first and last words: every field
+    of every lane equals the scalar engine bit for bit."""
+    builder, mech = EDGE_CASES[case]
+    pp = prepare(builder(), mech, virtualize=True)
+    regs = [{19: i + 1} for i in range(EDGE_LANES)]
+    refs = [run_prepared(pp, fuel=FUEL, regs=r) for r in regs]
+    out = run_fleet_prepared([pp] * EDGE_LANES, fuel=FUEL, regs=regs)
+    for i, ref in enumerate(refs):
+        assert int(ref.icount) > 0
+        _assert_lane_equal(ref, unstack_state(out, i), (case, i))

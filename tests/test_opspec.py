@@ -590,9 +590,9 @@ def _batch_inputs(batch):
 
 @jax.jit
 def _exec_batch(fields, st):
-    out, _ = F.exec_lanes(fields, st, None,
+    out, _ = F.exec_lanes(fields, F.flat_planes(st), None,
                           act=jnp.ones(st.pc.shape, bool))
-    return out
+    return F.lane_planes(out)
 
 
 _CHECK_FIELDS = ("regs", "sp", "pc", "nzcv", "mem", "cycles", "icount",

@@ -4,9 +4,11 @@ The TPU compiler compiles for a described ``v5e:2x2`` topology here, so
 these tests catch what only the chip's compiler refuses (layouts, tiling,
 programs that do not fit 16 GB) at no chip time.  Shapes are those of
 ``chip_smoke.py``: a 4096-lane pool, a 32-row image table, the streamed
-trace carry.  Nothing runs; only ``memory_analysis()`` is read.
+trace carry.  Nothing runs; only ``memory_analysis()`` and the compiled
+HLO text are read.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +26,13 @@ CHUNK = HookConfig().fleet_chunk
 TRACE_CAP = HookConfig().trace_cap
 HBM_BYTES = 16 * 10**9
 BUDGET = 0.75 * HBM_BYTES    # leave a quarter for the server's other buffers
+
+# temp_size_in_bytes of these two spans when each step still made its own
+# flat view of the [POOL, MEM_WORDS] plane (four whole-plane relayouts per
+# step); read from this file's compiles on the code of that time.  The flat
+# carry must not need more.
+TEMPS_WITH_PER_STEP_VIEWS = {"span": 3_417_509_888,
+                             "traced_span": 3_524_913_664}
 
 
 @pytest.fixture(scope="module")
@@ -79,21 +88,116 @@ def _fits(compiled):
     return mem
 
 
-def test_span_compiles_and_fits(one_chip):
-    """The untraced generation (``serve_gen_steps`` steps per dispatch)."""
-    imgs, ids, states, _ = _pool(one_chip)
+@pytest.fixture(scope="module")
+def compiled_spans(one_chip):
+    """The two span programs, compiled once for every test below."""
+    imgs, ids, states, trace = _pool(one_chip)
     span = HookConfig().serve_gen_steps // CHUNK
-    mem = _fits(F._jitted_span(CHUNK, span).lower(imgs, ids, states)
-                .compile())
+    sub = F.stream_interval(TRACE_CAP, CHUNK) // CHUNK
+    return {
+        "span": F._jitted_span(CHUNK, span).lower(imgs, ids, states)
+        .compile(),
+        "traced_span": F._jitted_span_traced(CHUNK, sub)
+        .lower(imgs, ids, states, trace).compile(),
+    }
+
+
+def test_span_compiles_and_fits(compiled_spans):
+    """The untraced generation (``serve_gen_steps`` steps per dispatch)."""
+    mem = _fits(compiled_spans["span"])
     assert mem.argument_size_in_bytes > POOL * L.MEM_WORDS * 8
 
 
-def test_traced_span_compiles_and_fits(one_chip):
+def test_traced_span_compiles_and_fits(compiled_spans):
     """The streamed sub-span the smoke dispatches (trace_cap steps)."""
-    imgs, ids, states, trace = _pool(one_chip)
-    span = F.stream_interval(TRACE_CAP, CHUNK) // CHUNK
-    _fits(F._jitted_span_traced(CHUNK, span).lower(imgs, ids, states, trace)
-          .compile())
+    _fits(compiled_spans["traced_span"])
+
+
+# -- the step body of the compiled span ---------------------------------------
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) .*\{\s*$")
+
+
+def _computations(hlo: str):
+    """HLO text -> ({computation name: instruction lines}, entry name)."""
+    comps, cur, entry = {}, None, None
+    for line in hlo.splitlines():
+        if cur is None:
+            m = _COMPUTATION.match(line)
+            if m:
+                cur = m.group(1)
+                comps[cur] = []
+                if line.startswith("ENTRY "):
+                    entry = cur
+        elif line.startswith("}"):
+            cur = None
+        else:
+            comps[cur].append(line)
+    return comps, entry
+
+
+def _instruction(line: str):
+    """``(opcode, result shape, operands and attributes)`` of one HLO
+    instruction line, or None.  A tuple shape is returned whole."""
+    s = line.strip()
+    if s.startswith("ROOT "):
+        s = s[5:]
+    if not s.startswith("%") or " = " not in s:
+        return None
+    rest = s.split(" = ", 1)[1]
+    if rest.startswith("("):
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        shape, rest = rest[:i + 1], rest[i + 2:]
+    else:
+        shape, _, rest = rest.partition(" ")
+    return rest.split("(", 1)[0], shape, rest
+
+
+def _while_bodies(comps, name):
+    return [re.search(r"body=%([^,\s]+)", rest).group(1)
+            for op, _, rest in filter(None, map(_instruction, comps[name]))
+            if op == "while"]
+
+
+def _step_body(hlo: str):
+    """Instructions of the step: the body of the chunk scan's ``while``
+    inside the span's ``while``, without the computations it calls
+    (conditional branches, the io and data loops, fusion bodies)."""
+    comps, entry = _computations(hlo)
+    (span_body,) = _while_bodies(comps, entry)
+    (step,) = _while_bodies(comps, span_body)
+    return list(filter(None, map(_instruction, comps[step])))
+
+
+def _elements(shape: str):
+    m = re.match(r"^[a-z0-9]+\[([\d,]*)\]", shape)
+    return int(np.prod([int(d) for d in m.group(1).split(",")])) \
+        if m and m.group(1) else None
+
+
+@pytest.mark.parametrize("span", ["span", "traced_span"])
+def test_span_step_body_moves_no_whole_plane(compiled_spans, span):
+    """Every step addresses the guest-memory plane where it lies: the step
+    body holds no reshape or copy of a whole plane (on a TPU each is a
+    relayout of the whole plane, 512 MiB per 32-bit half at this width)."""
+    body = _step_body(compiled_spans[span].as_text())
+    assert any(op == "scatter" or "scatter" in rest for op, _, rest in body)
+    moves = [(op, shape) for op, shape, _ in body
+             if op in ("reshape", "copy")
+             and _elements(shape) == POOL * L.MEM_WORDS]
+    assert not moves, moves
+
+
+@pytest.mark.parametrize("span", ["span", "traced_span"])
+def test_span_temps_no_larger_than_per_step_views(compiled_spans, span):
+    """Carrying the plane flat through the span needs no more scratch than
+    the per-step flat views it replaced."""
+    temps = compiled_spans[span].memory_analysis().temp_size_in_bytes
+    assert temps <= TEMPS_WITH_PER_STEP_VIEWS[span], temps
 
 
 def test_admission_scatter_compiles_and_fits(one_chip):
