@@ -18,9 +18,10 @@ stage of ``FleetServer.step()`` runs inside a named phase —
     obs_snapshot    sink snapshot writes (self-observation, priced too)
 
 A phase may open named child phases, written ``parent/child``, inside
-itself: they split admission, dispatch and harvest by cause (eager
-initial states vs the scatter, enqueue vs the wait for the chip,
-readback vs per-lane unstacking vs publishing).  Children report the
+itself: they split admission, dispatch, harvest and stream_flush by
+cause (eager initial states vs the scatter, enqueue vs the wait for the
+chip, readback vs per-lane unstacking vs publishing, the cold half's
+landing on the host vs its per-lane reassembly).  Children report the
 same fields as any phase; coverage counts top-level phases only, so a
 child's time is never counted twice.
 
@@ -60,6 +61,9 @@ PHASES = (
     # host transfers, C3 diagnosis and recycling, per-lane unstack + halt
     # patch, stream pop / histograms / charges / FleetResult
     "harvest/readback", "harvest/c3", "harvest/unstack", "harvest/publish",
+    # the device->host landing of a flipped cold-half block vs its
+    # per-lane reassembly into the stream
+    "stream_flush/copy", "stream_flush/reassemble",
 )
 TRACE_PREFIX = "fleet."
 

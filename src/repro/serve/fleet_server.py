@@ -1141,7 +1141,7 @@ class FleetServer:
                     engine=self.engine)
             if pending is not None:
                 with self._phase("stream_flush"):
-                    self._stream.push_block(keys, *pending)
+                    self._push_cold(keys, pending)
             with self._phase("dispatch"):
                 with self._phase("dispatch/device_wait"):
                     jax.block_until_ready(self._trace.count)
@@ -1151,11 +1151,19 @@ class FleetServer:
             pending = (cold, counts, bases)
             left -= steps
         with self._phase("stream_flush"):
-            self._stream.push_block(keys, *pending)
+            self._push_cold(keys, pending)
             # writers land before durability journals the emission
             # watermarks, so a recovered server never re-emits what a
             # sink already holds
             self._stream.flush()
+
+    def _push_cold(self, keys, pending) -> None:
+        """Land one flipped cold-half block on the host, then reassemble
+        it lane by lane into the stream."""
+        with self._phase("stream_flush/copy"):
+            cold, counts, bases = (np.asarray(a) for a in pending)
+        with self._phase("stream_flush/reassemble"):
+            self._stream.push_block(keys, cold, counts, bases)
 
     def _drop_request(self, req: FleetRequest, reason: str) -> None:
         """Load-shed one queued request: reject-with-reason, releasing any

@@ -300,7 +300,8 @@ def test_child_phases_nest_under_their_parents():
     for name in ("admission/initial_state", "admission/image_row",
                  "admission/scatter", "dispatch/enqueue",
                  "dispatch/device_wait", "harvest/readback", "harvest/c3",
-                 "harvest/unstack", "harvest/publish"):
+                 "harvest/unstack", "harvest/publish", "stream_flush/copy",
+                 "stream_flush/reassemble"):
         assert name in children, name
     for name in children:
         assert name in PHASES
@@ -308,7 +309,7 @@ def test_child_phases_nest_under_their_parents():
         assert set(phases[name]) == set(phases[parent])
         assert phases[name]["count"] >= 1
         assert phases[name]["total_s"] <= phases[parent]["total_s"]
-    for parent in ("admission", "dispatch", "harvest"):
+    for parent in ("admission", "dispatch", "harvest", "stream_flush"):
         kids = sum(phases[n]["total_s"] for n in children
                    if n.startswith(parent + "/"))
         assert kids <= phases[parent]["total_s"]
@@ -317,6 +318,23 @@ def test_child_phases_nest_under_their_parents():
     top = sum(p["total_s"] for n, p in phases.items() if "/" not in n)
     assert m["phase_coverage"] == pytest.approx(
         top / m["generation"]["total_s"])
+
+
+def test_stream_flush_children_explain_it():
+    """On a streamed server whose flushes carry the records of many lanes,
+    the cold half's landing on the host and its per-lane reassembly nest
+    inside ``stream_flush`` and explain at least 90% of it."""
+    cfg = HookConfig(obs_enabled=True, trace_enabled=True, trace_cap=16)
+    srv = FleetServer(pool=128, cfg=cfg, gen_steps=48, chunk=8, fuel=FUEL,
+                      stream=True)
+    for _ in range(128):
+        srv.submit(_pp("storm"), regs={19: 6, 20: 8, 21: 0}, tenant="t")
+    _drain(srv)
+    phases = srv.metrics()["phases"]
+    flush = phases["stream_flush"]
+    kids = [phases["stream_flush/copy"], phases["stream_flush/reassemble"]]
+    assert all(k["count"] == flush["count"] for k in kids)
+    assert sum(k["total_s"] for k in kids) >= 0.9 * flush["total_s"]
 
 
 def test_nested_inflight_exports_and_watermarks_stay_monotone():
@@ -394,6 +412,7 @@ def test_profiler_trace_holds_the_fleet_spans(tmp_path):
              for line in plane.lines for e in line.events}
     for name in ("fleet.admission", "fleet.admission/initial_state",
                  "fleet.dispatch/device_wait", "fleet.harvest/unstack",
+                 "fleet.stream_flush/copy", "fleet.stream_flush/reassemble",
                  "fleet.generation"):
         assert name in names, name
 
