@@ -707,8 +707,8 @@ def exec_lanes(fields, s: MachineState, tr: Optional[TraceState],
             lambda c: jnp.any(c[2]), io_lane_body, (mem, zero, io_do))
 
     # Guest-kernel bulk data (file/pipe/proc reads+writes, getrandom
-    # fills): the same bare-while-loop discipline over the (memory,
-    # inode-data) flat planes — zero iterations when no lane moves words.
+    # fills) over the (memory, inode-data) flat planes: a loop over the
+    # moving lanes only, behind a cond — no work when no lane moves words.
     with jax.named_scope("emul"):
         proc_flat = lax.cond(
             jnp.any(eff.src_is_proc),

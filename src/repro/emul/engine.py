@@ -16,14 +16,13 @@ scalar effects and the memory-word loop:
   vector math — no big-buffer access — and the whole call sits behind a
   batch-uniform ``lax.cond`` in the executor, so steps without an
   emulated syscall pay one ``jnp.any``.
-* :func:`run_data_loop` — the *data-plane* step: a per-lane while loop
-  (zero iterations when no lane moves data) that transfers up to
-  FILE_WORDS words per lane — in W_KIO-word windows, so cost tracks the
-  words actually moved — between guest memory, the inode data plane,
-  the synthetic /proc window and the getrandom stream, with the same
-  cond-wrapped dynamic-slice discipline as the executor's stream-I/O
-  loop (bare big-buffer reads would make XLA defensively copy the
-  carry).
+* :func:`run_data_loop` — the *data-plane* step: behind a batch-uniform
+  cond (no work when no lane moves data), it walks only the lanes that
+  move data, K_KIO at a time, and transfers up to FILE_WORDS words per
+  lane in W_KIO-word windows between guest memory, the inode data plane,
+  the synthetic /proc window and the getrandom stream.  Its cost tracks
+  the moving lanes and their words, not the fleet's width; the gathers
+  from the planes stay inside the loop body, so XLA copies no plane.
 
 Every transfer fits one FILE_WORDS window by construction: file and pipe
 payloads are capped by the FILE_BYTES inode size, getrandom short-reads
@@ -515,72 +514,102 @@ def proc_rows(s) -> jnp.ndarray:
     return jnp.concatenate([body, pad], axis=1)
 
 
-W_KIO = 128   # data-mover window: ceil(max nw / W_KIO) windows per step
+W_KIO = 64    # data-mover window: words per lane per gather/scatter
+# data-mover block: moving lanes per gather/scatter.  With blocks of 1,024
+# lanes or fewer the TPU compiler re-places the decode table of a
+# 4,096-lane step (an 8 MiB eviction and refetch every step, about 1% of a
+# getpid step); with 2,048 the table is sliced into VMEM once a step.
+K_KIO = 2048
 
 
 def run_data_loop(mem_flat, ino_flat, proc_flat, eff: EmulEffects):
-    """Move every data lane's words at once, in W_KIO-word windows.
+    """Move the data lanes' words, ``K_KIO`` lanes by ``W_KIO`` words at
+    a time.
 
-    One ``[B, W_KIO]`` masked gather + parked-index scatter per window,
-    all I/O lanes together, behind a batch-uniform ``lax.cond`` (zero
-    work on steps where no lane moves data) — the executor's
-    ``emul_result_words`` discipline, scaled up.  An earlier per-lane
-    while loop (one 512-word slice per lane per iteration) was
-    proportional-cost for sparse I/O but sequential in the number of
-    moving lanes: a census cell's lanes hit ``read`` in lockstep, so at
-    400 lanes the loop serialized ~80 window moves per syscall step and
-    doubled churn-census wall-clock.  Windows are lane-private (fd
-    buffers and inode regions never cross lanes), so live scatter
-    indices are genuinely unique; masked entries park on distinct
-    out-of-bounds slots and drop.  Returns ``(mem_flat, ino_flat)``.
+    Behind a batch-uniform ``lax.cond`` (zero work on steps where no lane
+    moves data) the moving lanes are ordered first, by a stable argsort
+    of ``fio_do``, and walked in blocks of ``K = min(B, K_KIO)``.  Each
+    block gathers its lanes' routing, then runs ceil(its longest transfer
+    / W_KIO) windows of one ``[K, W_KIO]`` masked gather and parked-index
+    scatter into each plane.  A TPU scatter costs the same per index
+    whether it lands or is parked, so the index count tracks the moving
+    lanes, ceil(n_move / K) * K * W_KIO a window, not the fleet's width:
+    at 4,096 lanes with 1,024 moving, half of a ``[B, W_KIO]`` window's.
+    Blocks keep many lanes in one scatter: an earlier per-lane loop (one
+    512-word slice per lane per iteration) was sequential in the number
+    of moving lanes, and a census cell's lanes hit ``read`` in lockstep.  Windows are lane-private (fd buffers and inode regions
+    never cross lanes), so live scatter indices are genuinely unique;
+    masked words and the slots past the last mover park on distinct
+    out-of-bounds ids and drop.  A fleet of at most ``K_KIO`` lanes (the
+    served rungs, the scalar engine) is one block, walked in place with no
+    ordering.  Returns ``(mem_flat, ino_flat)``.
     """
     B = eff.nw.shape[0]
+    K = min(B, K_KIO)
     W = W_KIO
     woff = jnp.arange(W, dtype=I64)
+    slots = jnp.arange(K, dtype=I64)
     MTOT = B * L.MEM_WORDS
     ITOT = B * _IPL
     PTOT = B * L.PROC_WORDS
-    park_m = jnp.int64(MTOT) + jnp.arange(B * W, dtype=I64)
-    park_i = jnp.int64(ITOT) + jnp.arange(B * W, dtype=I64)
+    park_m = (jnp.int64(MTOT) + jnp.arange(K * W, dtype=I64)).reshape(K, W)
+    park_i = (jnp.int64(ITOT) + jnp.arange(K * W, dtype=I64)).reshape(K, W)
 
-    def move(operands):
-        mf0, inf0 = operands
-        nwin = jnp.max(jnp.where(eff.fio_do,
-                                 (eff.nw + W - 1) // W, jnp.int64(0)))
-        rng = splitmix64(eff.rng0 * jnp.int64(0x10001) + 1)
-        to_mem = eff.fio_do & eff.dst_is_mem
-        to_ino = eff.fio_do & ~eff.dst_is_mem
+    def windows(pick, live, inner):
+        """Every window of one block: lane ``k`` of the block is the lane
+        whose routing ``pick`` selects; ``live`` masks the block's slots."""
+        nw = jnp.where(live, pick(eff.nw), jnp.int64(0))
+        mem_base = pick(eff.mem_base)[:, None]
+        ino_base = pick(eff.ino_base)[:, None]
+        proc_base = pick(eff.proc_base)[:, None]
+        src_is_rand = pick(eff.src_is_rand)[:, None]
+        src_is_proc = pick(eff.src_is_proc)[:, None]
+        to_mem = (live & pick(eff.dst_is_mem))[:, None]
+        to_ino = (live & ~pick(eff.dst_is_mem))[:, None]
+        rng = splitmix64(pick(eff.rng0) * jnp.int64(0x10001) + 1)
 
         def win_body(c, inner):
             mf, inf = inner
             rel = (c * W + woff)[None, :]                      # [1, W]
-            within = (rel < eff.nw[:, None])                   # [B, W]
+            within = rel < nw[:, None]                         # [K, W]
             # sources for guest-memory destinations (read/getrandom)
-            v_ino = inf[jnp.clip(eff.ino_base[:, None] + rel, 0, ITOT - 1)]
-            v_proc = proc_flat[jnp.clip(eff.proc_base[:, None] + rel,
-                                        0, PTOT - 1)]
+            v_ino = inf[jnp.clip(ino_base + rel, 0, ITOT - 1)]
+            v_proc = proc_flat[jnp.clip(proc_base + rel, 0, PTOT - 1)]
             v_rand = splitmix64(rng[:, None] + rel)
-            v = jnp.where(eff.src_is_rand[:, None], v_rand,
-                          jnp.where(eff.src_is_proc[:, None], v_proc, v_ino))
+            v = jnp.where(src_is_rand, v_rand,
+                          jnp.where(src_is_proc, v_proc, v_ino))
             # source for inode destinations (write): the guest buffer —
             # gathered before the mem scatter below; a lane is either a
             # reader or a writer this step and windows are lane-private,
             # so the ordering cannot alias
-            v_mem = mf[jnp.clip(eff.mem_base[:, None] + rel, 0, MTOT - 1)]
-            live_m = within & to_mem[:, None]
-            live_i = within & to_ino[:, None]
-            idx_m = jnp.where(live_m, eff.mem_base[:, None] + rel,
-                              park_m.reshape(B, W)).reshape(-1)
-            idx_i = jnp.where(live_i, eff.ino_base[:, None] + rel,
-                              park_i.reshape(B, W)).reshape(-1)
+            v_mem = mf[jnp.clip(mem_base + rel, 0, MTOT - 1)]
+            idx_m = jnp.where(within & to_mem, mem_base + rel,
+                              park_m).reshape(-1)
+            idx_i = jnp.where(within & to_ino, ino_base + rel,
+                              park_i).reshape(-1)
             mf = mf.at[idx_m].set(v.reshape(-1), mode="drop",
                                   unique_indices=True)
             inf = inf.at[idx_i].set(v_mem.reshape(-1), mode="drop",
                                     unique_indices=True)
             return mf, inf
 
-        return lax.fori_loop(jnp.int64(0), nwin, win_body, (mf0, inf0))
+        nwin = jnp.max((nw + W - 1) // W)
+        return lax.fori_loop(jnp.int64(0), nwin, win_body, inner)
 
-    mem_flat, ino_flat = lax.cond(jnp.any(eff.fio_do), move,
-                                  lambda o: o, (mem_flat, ino_flat))
-    return mem_flat, ino_flat
+    def move(operands):
+        if K == B:             # one block holds the fleet: walk it in place
+            return windows(lambda x: x, eff.fio_do, operands)
+        n_move = jnp.sum(eff.fio_do, dtype=I64)
+        order = jnp.argsort(~eff.fio_do, stable=True)     # movers first
+
+        def block_body(j, inner):
+            slot = j * K + slots
+            lane = order[jnp.minimum(slot, B - 1)]
+            return windows(lambda x: x[lane], slot < n_move, inner)
+
+        return lax.fori_loop(jnp.int64(0), (n_move + K - 1) // K,
+                             block_body, operands)
+
+    with jax.named_scope("data_loop"):
+        return lax.cond(jnp.any(eff.fio_do), move, lambda o: o,
+                        (mem_flat, ino_flat))

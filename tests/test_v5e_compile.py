@@ -19,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import fleet as F
 from repro.core import layout as L
 from repro.core.hookcfg import HookConfig
+from repro.emul import engine as E
 
 POOL = 4096
 TABLE_ROWS = 32
@@ -189,6 +190,74 @@ def test_span_step_body_moves_no_whole_plane(compiled_spans, span):
     moves = [(op, shape) for op, shape, _ in body
              if op in ("reshape", "copy")
              and _elements(shape) == POOL * L.MEM_WORDS]
+    assert not moves, moves
+
+
+def _called(rest: str):
+    """Names of the computations one instruction calls."""
+    names = re.findall(r"(?:calls|body|condition|to_apply|true_computation"
+                       r"|false_computation)=%([^,\s)}]+)", rest)
+    for group in re.findall(r"branch_computations=\{([^}]*)\}", rest):
+        names += [g.strip().lstrip("%") for g in group.split(",")]
+    return names
+
+
+def _data_loop(hlo: str):
+    """``{computation: [(name, opcode, shape, rest)]}`` of every computation
+    the guest kernel's data-loop cond reaches (its branches, the block and
+    window loops, their fusions)."""
+    comps, _ = _computations(hlo)
+
+    def named(lines):
+        out = []
+        for line in lines:
+            ins = _instruction(line)
+            if ins:
+                name = line.strip().removeprefix("ROOT ").split(" = ")[0]
+                out.append((name.lstrip("%"), *ins))
+        return out
+
+    (cond,) = [rest for lines in comps.values() for _, op, _, rest in
+               named(lines) if op == "conditional"
+               and '/data_loop/cond"' in rest]
+    todo, seen = _called(cond), {}
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen[c] = named(comps[c])
+            todo += [n for *_, rest in seen[c] for n in _called(rest)]
+    return seen
+
+
+def _operands(rest: str):
+    depth, i = 0, rest.index("(")
+    for j in range(i, len(rest)):
+        depth += (rest[j] == "(") - (rest[j] == ")")
+        if depth == 0:
+            break
+    return re.findall(r"%([^,\s)]+)", rest[i:j])
+
+
+def test_data_loop_indices_track_moving_lanes(compiled_spans):
+    """The traced span's data loop gathers and scatters ``[K, W_KIO]``
+    windows of the moving lanes, never a ``[POOL, W_KIO]`` window of the
+    whole fleet, and copies or reshapes no whole ``mem`` or ``k_ino_data``
+    plane (each would be a relayout of the plane on a TPU)."""
+    comps = _data_loop(compiled_spans["traced_span"].as_text())
+    most = min(POOL, E.K_KIO) * E.W_KIO
+    planes = (POOL * L.MEM_WORDS, POOL * L.MAX_INODES * L.FILE_WORDS)
+    counts, moves = [], []
+    for body in comps.values():
+        shapes = {name: shape for name, _, shape, _ in body}
+        for name, op, shape, rest in body:
+            if op in ("gather", "scatter"):
+                args = _operands(rest)
+                idx = args[1] if op == "gather" else args[(len(args) - 1) // 2]
+                counts.append((op, name, _elements(shapes[idx])))
+            elif op in ("reshape", "copy") and _elements(shape) in planes:
+                moves.append((op, name, shape))
+    assert any(op == "scatter" for op, *_ in counts), counts
+    assert all(n <= most < POOL * E.W_KIO for *_, n in counts), counts
     assert not moves, moves
 
 
